@@ -670,18 +670,12 @@ def run_obs(quick: bool = False, gate: bool = False,
         sched.submit(ServeRequest(prompt=p, max_new_tokens=tokens,
                                   request_id=f"obs-{i}"))
     d0 = backend.dispatch_stats().dispatches
-    profiling = False
     if profile_dir:
-        try:
-            jax.profiler.start_trace(profile_dir)
-            profiling = True
-        except Exception as e:         # profiler plugin absent: obs-only run
-            print(f"  → jax.profiler unavailable ({e}); "
-                  "emitting the obs trace only")
+        jax.profiler.start_trace(profile_dir)
     try:
         sched.run()
     finally:
-        if profiling:
+        if profile_dir:
             jax.profiler.stop_trace()
     st = sched.last_stats
     delta = backend.dispatch_stats().dispatches - d0
@@ -703,7 +697,7 @@ def run_obs(quick: bool = False, gate: bool = False,
           f"dispatch total {trace_total} vs dispatch_stats delta {delta}; "
           f"decode spans {decode_spans} vs cycles {st.cycles}")
     print(f"  artifacts: {trace_path}, {metrics_path}"
-          + (f", {profile_dir}/" if profiling else ""))
+          + (f", {profile_dir}/" if profile_dir else ""))
 
     # per-backend §7.2 decomposition: 1-dispatch model vs per-op F3 graph
     rng2 = np.random.default_rng(12)

@@ -7,6 +7,8 @@ import platform
 import time
 from typing import Any, Dict, List
 
+import jax
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -18,8 +20,12 @@ def save_results(name: str, payload: Any) -> str:
     uploads as an artifact and gates regressions against."""
     os.makedirs(RESULTS_DIR, exist_ok=True)
     path = os.path.join(RESULTS_DIR, f"results_{name}.json")
+    dev = jax.devices()[0]
     doc = {
         "benchmark": name,
+        # the device every number below was measured on
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "host": platform.machine(),
         "python": platform.python_version(),
         "time": time.time(),

@@ -97,8 +97,15 @@ _FUSED_BACKEND = "xla"
 
 
 def set_fused_backend(name: str) -> None:
+    """Select the fused-op bodies for every graph traced from now on.
+
+    The choice is read while tracing, and jit keeps one trace per function
+    and argument shapes across all its wrappers, so a switch also drops
+    the traces made under the other backend."""
     global _FUSED_BACKEND
     assert name in ("xla", "pallas"), name
+    if name != _FUSED_BACKEND:
+        jax.clear_caches()
     _FUSED_BACKEND = name
 
 
@@ -125,22 +132,9 @@ def _fused_mlp(x, wg, wu):
 
 def _fused_kv(x, wkv, bkv):
     if _FUSED_BACKEND == "pallas":
-        # kv_proj_pallas consumes the concatenated [Wk|Wv] directly
-        from repro.kernels.common import pad_dim, round_up, use_interpret
-        from repro.kernels.fused_kv_proj.kernel import kv_proj_pallas
-        shape = x.shape
-        d, n = wkv.shape
-        rows = 1
-        for s in shape[:-1]:
-            rows *= s
-        bm, bn, bk = 128, 128, 128
-        mp, kp, np_ = round_up(rows, bm), round_up(d, bk), round_up(n, bn)
-        out = kv_proj_pallas(
-            pad_dim(pad_dim(x.reshape(rows, d), 0, mp), 1, kp),
-            pad_dim(pad_dim(jnp.asarray(wkv), 0, kp), 1, np_),
-            pad_dim(jnp.asarray(bkv), 0, np_),
-            block_m=bm, block_n=bn, block_k=bk, interpret=use_interpret())
-        return out[:rows, :n].reshape(*shape[:-1], n)
+        # the kernel consumes the concatenated [Wk|Wv] directly
+        from repro.kernels.fused_kv_proj.ops import kv_proj
+        return kv_proj(x, jnp.asarray(wkv), jnp.asarray(bkv))
     y = jnp.einsum("...d,df->...f", x, wkv, preferred_element_type=jnp.float32)
     return (y + bkv.astype(jnp.float32)).astype(x.dtype)
 
@@ -170,7 +164,7 @@ OPS: Dict[str, Callable] = {
     "softmax": lambda x: jax.nn.softmax(x.astype(jnp.float32), axis=-1),
     "cast": lambda x, *, dtype: x.astype(dtype),
     "cache_update": lambda cache, val, pos: jax.lax.dynamic_update_slice(
-        cache, val, (0, pos, 0, 0)),
+        cache, val.astype(cache.dtype), (0, pos, 0, 0)),
     # per-row scatter for slot-position decode graphs (continuous batching):
     # row b writes at its own position pos[b] instead of a shared offset
     "cache_update_rows": lambda cache, val, pos: cache.at[

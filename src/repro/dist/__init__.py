@@ -20,34 +20,6 @@ The serving integration is ``repro.serving.backends.dist`` (registry key
 """
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None):
-    """Version-portable ``shard_map``.
-
-    jax ≥ 0.6 exposes ``jax.shard_map`` with a ``check_vma`` flag; the
-    pinned 0.4.x toolchain has ``jax.experimental.shard_map.shard_map``
-    with the equivalent ``check_rep``.  Callers may pass either spelling.
-    """
-    if check_rep is None:
-        check_rep = True if check_vma is None else check_vma
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        try:
-            return native(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-        except TypeError:
-            return native(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
-
 from repro.dist.compression import (CompressionConfig, compress_gradients,
                                     compressed_psum_mean, dequantize_int8,
                                     quantize_int8, uncompressed_psum_mean)
@@ -56,7 +28,6 @@ from repro.dist.pipeline import (PipelineStats, bubble_fraction,
                                  pipeline_apply, pipeline_stats)
 
 __all__ = [
-    "shard_map",
     "PipelineStats", "bubble_fraction", "pipeline_apply", "pipeline_stats",
     "CompressionConfig", "compress_gradients", "compressed_psum_mean",
     "dequantize_int8", "quantize_int8", "uncompressed_psum_mean",

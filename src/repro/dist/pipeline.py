@@ -86,8 +86,6 @@ def _compiled_pipeline(mesh: Mesh, axis: str, stage_fn: Callable,
     """One jitted pipeline executable per (mesh, stage_fn, schedule) —
     repeat calls with the same shapes reuse jit's compilation cache
     instead of retracing a fresh closure every time."""
-    from repro.dist import shard_map
-
     perm = ring_perm(n_stages)
     last = n_stages - 1
 
@@ -114,8 +112,8 @@ def _compiled_pipeline(mesh: Mesh, axis: str, stage_fn: Callable,
 
     in_specs = (jax.tree_util.tree_unflatten(
         w_treedef, [P(axis)] * w_treedef.num_leaves), P())
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))
 
 
 def pipeline_apply(w: Any, x: jax.Array, *, mesh: Mesh,

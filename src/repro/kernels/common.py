@@ -1,9 +1,9 @@
 """Shared kernel utilities: interpret-mode selection and padding helpers.
 
-TPU is the TARGET; this container is CPU-only, so kernels execute under
-``interpret=True`` (the kernel body runs as JAX ops on CPU) for
-correctness validation.  On a real TPU backend the same ``pallas_call``
-lowers to Mosaic.
+TPU is the target.  On a TPU backend every ``pallas_call`` lowers to
+Mosaic.  On the CPU backend (the test suite runs there with
+``JAX_PLATFORMS=cpu``) kernels execute under ``interpret=True``, the
+kernel body running as JAX ops, for correctness validation only.
 """
 from __future__ import annotations
 
@@ -11,16 +11,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed pltpu.TPUCompilerParams → pltpu.CompilerParams; resolve
-# whichever this jax provides so kernels work on both sides of the rename.
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 @functools.lru_cache(None)
 def use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend, False on a TPU; any other backend is an
+    error, so a kernel never silently interprets on an accelerator."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on the {backend!r} "
+                           "backend; use a TPU, or the CPU in interpret mode")
+    return backend == "cpu"
 
 
 def round_up(x: int, m: int) -> int:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -36,15 +34,17 @@ def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[0]
     q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)                  # (bs, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)                  # (bs, D)
+    k = k_ref[0, 0].astype(jnp.float32)                     # (bs, D)
+    v = v_ref[0, 0].astype(jnp.float32)                     # (bs, D)
 
     pos = s_idx * block_s + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
     valid = pos < length                                     # (1, bs)
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(valid, scores, NEG_INF)               # (G, bs)
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale          # (G, bs)
+    scores = jnp.where(valid, scores, NEG_INF)
 
     m_old = m_ref[...]                                       # (G, 1)
     m_new = jnp.maximum(m_old, jnp.max(scores, axis=-1, keepdims=True))
@@ -65,30 +65,34 @@ def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             length: jax.Array, *, block_s: int = 128,
                             interpret: bool = False) -> jax.Array:
-    """q (B, KV, G, D); k/v (B, S, KV, D); length (1, 1) int32 → (B, KV, G, D)."""
+    """q (B, KV, G, D); k/v (B, KV, S, D); length (1,) int32 → (B, KV, G, D).
+
+    K/V are head-major so each block's last two dimensions are
+    ``(block_s, D)``, the (8, 128)-tiled layout Mosaic requires; the
+    length is scalar-prefetched into SMEM.
+    """
     b, kv, g, d = q.shape
-    s = k.shape[1]
+    s = k.shape[2]
     n_s = s // block_s
     scale = 1.0 / (d ** 0.5)
-    grid = (b, kv, n_s)
+    kv_spec = pl.BlockSpec((1, 1, block_s, d),
+                           lambda bb, hh, ss, ln: (bb, hh, ss, 0))
+    qo_spec = pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss, ln: (bb, hh, 0, 0))
     return pl.pallas_call(
         functools.partial(_decode_attn_kernel, n_s=n_s, block_s=block_s,
                           scale=scale),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, hh, ss: (0, 0)),   # length scalar
-            pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda bb, hh, ss: (bb, ss, hh, 0)),
-            pl.BlockSpec((1, block_s, 1, d), lambda bb, hh, ss: (bb, ss, hh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, hh, ss: (bb, hh, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, n_s),
+            in_specs=[qo_spec, kv_spec, kv_spec],
+            out_specs=qo_spec,
+            scratch_shapes=[
+                pltpu.VMEM((g, 1), jnp.float32),    # running max
+                pltpu.VMEM((g, 1), jnp.float32),    # running denom
+                pltpu.VMEM((g, d), jnp.float32),    # running numerator
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),    # running max
-            pltpu.VMEM((g, 1), jnp.float32),    # running denom
-            pltpu.VMEM((g, d), jnp.float32),    # running numerator
-        ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length, q, k, v)

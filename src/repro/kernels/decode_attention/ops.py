@@ -6,7 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import round_up, use_interpret
+from repro.kernels.common import pad_dim, round_up, use_interpret
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 
 
@@ -20,11 +20,10 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qg = q.reshape(b, kv, g, d) if one == 1 else None
     assert qg is not None, "decode attention is single-token"
     sp = round_up(s, block_s)
-    if sp != s:
-        pad = ((0, 0), (0, sp - s), (0, 0), (0, 0))
-        k_cache = jnp.pad(k_cache, pad)
-        v_cache = jnp.pad(v_cache, pad)
-    ln = jnp.asarray(length, jnp.int32).reshape(1, 1)
-    out = decode_attention_pallas(qg, k_cache, v_cache, ln,
+    # head-major (B, KV, S, D): the kernel's K/V blocks end in (block_s, D)
+    kh = pad_dim(jnp.swapaxes(k_cache, 1, 2), 2, sp)
+    vh = pad_dim(jnp.swapaxes(v_cache, 1, 2), 2, sp)
+    ln = jnp.asarray(length, jnp.int32).reshape(1)
+    out = decode_attention_pallas(qg, kh, vh, ln,
                                   block_s=block_s, interpret=use_interpret())
     return out.reshape(b, 1, h, d)

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import TPUCompilerParams
-
 
 def _kv_proj_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -37,7 +35,11 @@ def _kv_proj_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, n_k: int):
 def kv_proj_pallas(x: jax.Array, wkv: jax.Array, bkv: jax.Array, *,
                    block_m: int = 128, block_n: int = 128,
                    block_k: int = 128, interpret: bool = False) -> jax.Array:
-    """x (M, D) @ wkv (D, 2·Nkv) + bkv → (M, 2·Nkv)."""
+    """x (M, D) @ wkv (D, 2·Nkv) + bkv (2·Nkv,) → (M, 2·Nkv).
+
+    The bias rides as a ``(1, N)`` row so its block ``(1, block_n)`` meets
+    Mosaic's tiling for any dtype (a rank-1 bf16 block must span 256).
+    """
     m, d = x.shape
     _, n = wkv.shape
     n_k = d // block_k
@@ -48,12 +50,12 @@ def kv_proj_pallas(x: jax.Array, wkv: jax.Array, bkv: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, wkv, bkv)
+    )(x, wkv, bkv.reshape(1, n))

@@ -16,16 +16,25 @@ def fused_kv_proj(x: jax.Array, wk: jax.Array, wv: jax.Array,
                   block_m: int = 128, block_n: int = 128,
                   block_k: int = 128) -> jax.Array:
     """Returns concat([x·Wk+bk, x·Wv+bv], -1); split is a (free) shape op."""
-    shape = x.shape
-    d = shape[-1]
-    rows = 1
-    for s in shape[:-1]:
-        rows *= s
     wkv = jnp.concatenate([wk, wv], axis=-1)
     if bk is None:
         bkv = jnp.zeros((wkv.shape[-1],), x.dtype)
     else:
         bkv = jnp.concatenate([bk, bv])
+    return kv_proj(x, wkv, bkv, block_m=block_m, block_n=block_n,
+                   block_k=block_k)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
+def kv_proj(x: jax.Array, wkv: jax.Array, bkv: jax.Array, *,
+            block_m: int = 128, block_n: int = 128,
+            block_k: int = 128) -> jax.Array:
+    """x (..., D) @ wkv (D, N) + bkv (N,) on the kernel, padded to blocks."""
+    shape = x.shape
+    d = shape[-1]
+    rows = 1
+    for s in shape[:-1]:
+        rows *= s
     n = wkv.shape[-1]
     x2 = x.reshape(rows, d)
     mp, kp, np_ = round_up(rows, block_m), round_up(d, block_k), round_up(n, block_n)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import TPUCompilerParams
-
 
 def _fused_mlp_kernel(x_ref, wg_ref, wu_ref, o_ref, acc_g, acc_u, *, n_k: int):
     k = pl.program_id(2)
@@ -56,7 +54,7 @@ def fused_mlp_pallas(x: jax.Array, wg: jax.Array, wu: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32),
                         pltpu.VMEM((block_m, block_f), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wg, wu)

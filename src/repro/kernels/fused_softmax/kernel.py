@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import TPUCompilerParams
-
 
 def _softmax_kernel(x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -37,7 +35,7 @@ def softmax_pallas(x: jax.Array, *, block_rows: int = 8,
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

@@ -17,7 +17,7 @@ import math
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -35,9 +35,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
             f"mesh {shape} needs {n} devices, have {len(devs)} — the dry-run "
             "must set XLA_FLAGS=--xla_force_host_platform_device_count "
             "before any jax import")
-    if len(devs) == n:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def small_test_mesh(axes: Tuple[str, ...] = ("data", "model")) -> Optional[Mesh]:

@@ -26,9 +26,11 @@ import numpy as np
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", "--config", dest="model", default="bench-0.5b",
-                    help="bench-0.5b | bench-1.5b | any registry arch "
-                         "(smoke-reduced), including the recurrent families "
-                         "mamba2-1.3b / recurrentgemma-9b")
+                    help="bench-0.5b | bench-1.5b | any registry arch at its "
+                         "published config, including the recurrent "
+                         "families mamba2-1.3b / recurrentgemma-9b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="registry archs: use the reduced (CPU-sized) config")
     ap.add_argument("--modes", default="F0,F3,FULL,model")
     ap.add_argument("--tokens", type=int, default=50)
     ap.add_argument("--prompt-len", type=int, default=5)
@@ -79,7 +81,7 @@ def main() -> None:
                          "TTFT/TPOT/queue-wait, dispatch counters) here")
     args = ap.parse_args()
 
-    from repro.configs import REGISTRY, get_smoke_config
+    from repro.configs import REGISTRY, get_config, get_smoke_config
     from repro.configs.bench import BENCH_MODELS
     from repro.models import build_model
     from repro.obs import MetricsRegistry, Tracer, write_metrics, write_trace
@@ -91,7 +93,8 @@ def main() -> None:
     if args.model in BENCH_MODELS:
         cfg = BENCH_MODELS[args.model]
     elif args.model in REGISTRY:
-        cfg = get_smoke_config(args.model)
+        cfg = (get_smoke_config(args.model) if args.smoke
+               else get_config(args.model))
     else:
         raise SystemExit(f"unknown model {args.model}")
 
@@ -175,4 +178,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -39,7 +39,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import RunStats
@@ -87,7 +87,8 @@ class DistBackend(ExecutionBackend):
         self.max_len = max_len
         self.stages = n_stages
         self.mesh = jax.make_mesh((n_stages,), ("stage",),
-                                  devices=devs[:n_stages])
+                                  devices=devs[:n_stages],
+                                  axis_types=(AxisType.Auto,))
 
         # layer-stacked leaves → P("stage") on the stack axis; the rest
         # (embed / final_norm / lm_head) replicate across stages
@@ -173,15 +174,14 @@ class DistBackend(ExecutionBackend):
         body = self._pipeline_blocks(block_step)
 
         def run(blocks, x):
-            from repro.dist import shard_map
             kv0 = (jnp.zeros(kv_shape, jnp.dtype(cfg.dtype)),
                    jnp.zeros(kv_shape, jnp.dtype(cfg.dtype)))
-            fn = shard_map(lambda bl, xx: body(bl, xx, kv0),
-                           mesh=self.mesh,
-                           in_specs=(jax.tree.map(lambda _: P("stage"),
-                                                  blocks), P()),
-                           out_specs=(P(), (P("stage"), P("stage"))),
-                           check_rep=False)
+            fn = jax.shard_map(lambda bl, xx: body(bl, xx, kv0),
+                               mesh=self.mesh,
+                               in_specs=(jax.tree.map(lambda _: P("stage"),
+                                                      blocks), P()),
+                               out_specs=(P(), (P("stage"), P("stage"))),
+                               check_vma=False)
             return fn(blocks, x)
 
         x, (kcache, vcache) = run(params["blocks"], x)
@@ -211,14 +211,13 @@ class DistBackend(ExecutionBackend):
         body = self._pipeline_blocks(block_step)
 
         def run(blocks, x, kc, vc):
-            from repro.dist import shard_map
-            fn = shard_map(lambda bl, xx, k, v: body(bl, xx, (k, v)),
-                           mesh=self.mesh,
-                           in_specs=(jax.tree.map(lambda _: P("stage"),
-                                                  blocks), P(),
-                                     P("stage"), P("stage")),
-                           out_specs=(P(), (P("stage"), P("stage"))),
-                           check_rep=False)
+            fn = jax.shard_map(lambda bl, xx, k, v: body(bl, xx, (k, v)),
+                               mesh=self.mesh,
+                               in_specs=(jax.tree.map(lambda _: P("stage"),
+                                                      blocks), P(),
+                                         P("stage"), P("stage")),
+                               out_specs=(P(), (P("stage"), P("stage"))),
+                               check_vma=False)
             return fn(blocks, x, kc, vc)
 
         x, (kcache, vcache) = run(params["blocks"], x, cache["k"], cache["v"])
@@ -269,14 +268,13 @@ class DistBackend(ExecutionBackend):
             return body(blocks_local, xx, init)
 
         def run(blocks, x, ak, av, table, pos):
-            from repro.dist import shard_map
-            fn = shard_map(inner, mesh=self.mesh,
-                           in_specs=(jax.tree.map(lambda _: P("stage"),
-                                                  blocks), P(),
-                                     P(None, "stage"), P(None, "stage"),
-                                     P(), P()),
-                           out_specs=(P(), (P("stage"), P("stage"))),
-                           check_rep=False)
+            fn = jax.shard_map(inner, mesh=self.mesh,
+                               in_specs=(jax.tree.map(lambda _: P("stage"),
+                                                      blocks), P(),
+                                         P(None, "stage"), P(None, "stage"),
+                                         P(), P()),
+                               out_specs=(P(), (P("stage"), P("stage"))),
+                               check_vma=False)
             return fn(blocks, x, ak, av, table, pos)
 
         x, (knew, vnew) = run(params["blocks"], x, ak, av, table, pos)
@@ -322,14 +320,13 @@ class DistBackend(ExecutionBackend):
             return body(blocks_local, xx, init)
 
         def run(blocks, x, ak, av, table_row, pos0):
-            from repro.dist import shard_map
-            fn = shard_map(inner, mesh=self.mesh,
-                           in_specs=(jax.tree.map(lambda _: P("stage"),
-                                                  blocks), P(),
-                                     P(None, "stage"), P(None, "stage"),
-                                     P(), P()),
-                           out_specs=(P(), (P("stage"), P("stage"))),
-                           check_rep=False)
+            fn = jax.shard_map(inner, mesh=self.mesh,
+                               in_specs=(jax.tree.map(lambda _: P("stage"),
+                                                      blocks), P(),
+                                         P(None, "stage"), P(None, "stage"),
+                                         P(), P()),
+                               out_specs=(P(), (P("stage"), P("stage"))),
+                               check_vma=False)
             return fn(blocks, x, ak, av, table_row, pos0)
 
         x, (kch, vch) = run(params["blocks"], x, ak, av, table_row, pos0)

@@ -44,7 +44,8 @@ def test_sharded_train_step_matches_single_device():
         cfg = get_smoke_config("qwen2-1.5b", layers=2, d_model=64, heads=4,
                                d_ff=128, vocab=256)
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         fn = S.train_step_fn(model)
         rng = jax.random.PRNGKey(0)
         state = init_state(model, rng, adamw(AdamWConfig()))
@@ -92,7 +93,6 @@ def test_compressed_psum_error_bounded():
     _run("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.dist import shard_map
         from repro.dist.compression import (compressed_psum_mean,
                                             uncompressed_psum_mean)
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
@@ -104,10 +104,11 @@ def test_compressed_psum_error_bounded():
             exact = uncompressed_psum_mean(g)
             return mean, exact, e2
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(("pod", "data")), P()),
-                       out_specs=(P(("pod", "data")), P(("pod", "data")), P()),
-                       check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(("pod", "data")), P()),
+                           out_specs=(P(("pod", "data")), P(("pod", "data")),
+                                      P()),
+                           check_vma=False)
         mean, exact, e2 = fn(g, err0)
         rel = float(jnp.max(jnp.abs(mean - exact)) / jnp.max(jnp.abs(exact)))
         assert rel < 0.05, f"int8 hop error too large: {rel}"

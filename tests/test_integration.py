@@ -44,6 +44,50 @@ def test_pallas_fused_backend_matches_xla():
                                np.asarray(ref["logits"]), atol=1e-3)
 
 
+def test_fused_backend_switch_retraces(monkeypatch):
+    """A per-op executable traced under "xla" must not be reused after a
+    switch to "pallas": jit shares traces across wrappers of one function,
+    so the switch has to drop them."""
+    from repro.kernels.fused_kv_proj import ops as kv_ops
+    from repro.serving import create_backend
+    calls = []
+    real = kv_ops.kv_proj
+    monkeypatch.setattr(kv_ops, "kv_proj",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    cfg = get_smoke_config("qwen2-1.5b", layers=1)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompt = np.array([[3, 5, 7, 9]], np.int32)
+    try:
+        for fused in ("xla", "pallas"):
+            opgraph.set_fused_backend(fused)
+            create_backend("F3", model, params, batch=1,
+                           max_len=16).prefill(prompt)
+            assert bool(calls) == (fused == "pallas")
+    finally:
+        opgraph.set_fused_backend("xla")
+
+
+@pytest.mark.parametrize("mode", ["F3", "FULL"])
+def test_graph_backend_serves_bf16(mode):
+    """The op-graph backends run a bf16 model (the published dtype) and
+    agree with the jitted model backend within bf16 rounding."""
+    from repro.serving import create_backend
+    cfg = get_smoke_config("qwen2-1.5b", layers=2).replace(dtype="bfloat16")
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompt = np.array([[3, 5, 7, 9, 11]], np.int32)
+    logits = {}
+    for m in (mode, "model"):
+        backend = create_backend(m, model, params, batch=1, max_len=16)
+        state, out = backend.prefill(prompt)
+        _, out = backend.decode_step(state, out.next_token)
+        logits[m] = np.asarray(out.logits, np.float32).reshape(-1)
+    ref = logits["model"]
+    rel = np.linalg.norm(logits[mode] - ref) / np.linalg.norm(ref)
+    assert rel < 0.05, rel
+
+
 def test_activation_policy_is_noop_without_mesh():
     """constrain_hidden under a policy but outside a mesh must not alter
     values (smoke-test safety)."""

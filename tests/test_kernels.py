@@ -114,3 +114,20 @@ def test_kernels_fuse_identically_to_model_layers(rng):
     w = jax.random.normal(jax.random.PRNGKey(1), (128,), jnp.float32)
     np.testing.assert_allclose(np.asarray(fused_rmsnorm(x, w)),
                                np.asarray(L.rmsnorm(x, w)), atol=2e-5)
+
+
+def test_use_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode is the CPU's alone: a TPU compiles the kernels, and
+    any other backend is refused rather than silently interpreted."""
+    from repro.kernels import common
+    try:
+        for backend, want in (("cpu", True), ("tpu", False)):
+            common.use_interpret.cache_clear()
+            monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+            assert common.use_interpret() is want
+        common.use_interpret.cache_clear()
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            common.use_interpret()
+    finally:
+        common.use_interpret.cache_clear()

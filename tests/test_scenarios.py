@@ -202,7 +202,7 @@ def test_serve_cli_names_missing_capability(monkeypatch):
     state_kind) instead of silently skipping the scheduler run."""
     from repro.launch import serve
     monkeypatch.setattr("sys.argv", [
-        "serve", "--config", "mamba2-1.3b", "--modes", "model",
+        "serve", "--config", "mamba2-1.3b", "--smoke", "--modes", "model",
         "--tokens", "2", "--runs", "1", "--warmup", "0",
         "--num-slots", "2", "--kv-layout", "paged"])
     with pytest.raises(SystemExit, match="paged_kv=False.*recurrent"):
