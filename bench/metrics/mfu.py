@@ -1,0 +1,2 @@
+"""mfu: see ``bench.readers.mfu``."""
+from bench.readers import mfu as read  # noqa: F401
