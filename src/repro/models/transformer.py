@@ -138,6 +138,17 @@ def ffn_block(p: Params, cfg: ModelConfig, x: jax.Array
     return L.swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), jnp.float32(0.0)
 
 
+def mlp_residual(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """``x + FFN(ffn_norm(x))`` (MoE aux loss dropped), under the ``mlp``
+    named scope so the profiler can tell the MLP's ops from the rest of
+    the step; the residual add sits inside, so a fusion rooted at it
+    still counts to the MLP."""
+    with jax.named_scope("mlp"):
+        f, _ = ffn_block(p["ffn"], cfg,
+                         L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
+        return x + f
+
+
 def block_forward(p: Params, cfg: ModelConfig, x: jax.Array,
                   positions: jax.Array, *, chunked: bool
                   ) -> Tuple[jax.Array, jax.Array]:
@@ -247,8 +258,7 @@ def prefill_block(p: Params, cfg: ModelConfig, x: jax.Array,
     else:
         o = L.causal_attention(q, k, v, window=cfg.sliding_window)
     x = constrain_hidden(x + L.linear(o.reshape(b, s, -1), p["attn"]["wo"]))
-    f, _ = ffn_block(p["ffn"], cfg, L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
-    x = constrain_hidden(x + f)
+    x = constrain_hidden(mlp_residual(p, cfg, x))
     kc = jnp.zeros((b, max_len, cfg.num_kv_heads, h), k.dtype)
     vc = jnp.zeros_like(kc)
     kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
@@ -295,8 +305,7 @@ def decode_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     vc = jax.lax.dynamic_update_slice(vc, v, (0, pos, 0, 0))
     o = L.decode_attention(q, kc, vc, pos + 1, window=cfg.sliding_window)
     x = x + L.linear(o.reshape(b, 1, -1), p["attn"]["wo"])
-    f, _ = ffn_block(p["ffn"], cfg, L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
-    return x + f, (kc, vc)
+    return mlp_residual(p, cfg, x), (kc, vc)
 
 
 def decode_step(params: Params, cfg: ModelConfig, cache: Params,
@@ -336,18 +345,18 @@ def decode_core_rows(p: Params, cfg: ModelConfig, x: jax.Array,
     """
     b = x.shape[0]
     positions = pos[:, None]                         # (B, 1)
-    xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
-    q, k, v = _project_qkv(p["attn"], cfg, xn)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
-    rows = jnp.arange(b)
-    kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
-    vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
-    o = L.decode_attention(q, kc, vc, pos + 1, window=cfg.sliding_window)
-    x = x + L.linear(o.reshape(b, 1, -1), p["attn"]["wo"])
-    f, _ = ffn_block(p["ffn"], cfg, L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
+    with jax.named_scope("attn"):
+        xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
+        q, k, v = _project_qkv(p["attn"], cfg, xn)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        rows = jnp.arange(b)
+        kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
+        o = L.decode_attention(q, kc, vc, pos + 1, window=cfg.sliding_window)
+        x = x + L.linear(o.reshape(b, 1, -1), p["attn"]["wo"])
     out = (kc, vc) if emit_cache else (k[:, 0], v[:, 0])
-    return x + f, out
+    return mlp_residual(p, cfg, x), out
 
 
 def decode_block_rows(p: Params, cfg: ModelConfig, x: jax.Array,
@@ -373,17 +382,19 @@ def extend_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     whole-prompt prefill, which is the chunking parity argument.
     """
     b, s, _ = x.shape
-    xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
-    q, k, v = _project_qkv(p["attn"], cfg, xn)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
-    kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, pos0, 0, 0))
-    vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, pos0, 0, 0))
-    o = L.causal_attention(q, kc, vc, q_offset=pos0,
-                           window=cfg.sliding_window)
-    x = x + L.linear(o.reshape(b, s, -1), p["attn"]["wo"])
-    f, _ = ffn_block(p["ffn"], cfg, L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
-    return x + f, (k, v)
+    with jax.named_scope("attn"):
+        xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
+        q, k, v = _project_qkv(p["attn"], cfg, xn)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                          (0, pos0, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                          (0, pos0, 0, 0))
+        o = L.causal_attention(q, kc, vc, q_offset=pos0,
+                               window=cfg.sliding_window)
+        x = x + L.linear(o.reshape(b, s, -1), p["attn"]["wo"])
+    return mlp_residual(p, cfg, x), (k, v)
 
 
 def verify_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
@@ -404,18 +415,18 @@ def verify_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     positions land past the committed ``pos`` and are simply overwritten.
     """
     b, c, _ = x.shape
-    xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
-    q, k, v = _project_qkv(p["attn"], cfg, xn)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
-    rows = jnp.arange(b)
-    kc = kc.at[rows[:, None], positions].set(k.astype(kc.dtype))
-    vc = vc.at[rows[:, None], positions].set(v.astype(vc.dtype))
-    o = L.causal_attention(q, kc, vc, q_offset=pos,
-                           window=cfg.sliding_window)
-    x = x + L.linear(o.reshape(b, c, -1), p["attn"]["wo"])
-    f, _ = ffn_block(p["ffn"], cfg, L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
-    return x + f, (k, v)
+    with jax.named_scope("attn"):
+        xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
+        q, k, v = _project_qkv(p["attn"], cfg, xn)
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        rows = jnp.arange(b)
+        kc = kc.at[rows[:, None], positions].set(k.astype(kc.dtype))
+        vc = vc.at[rows[:, None], positions].set(v.astype(vc.dtype))
+        o = L.causal_attention(q, kc, vc, q_offset=pos,
+                               window=cfg.sliding_window)
+        x = x + L.linear(o.reshape(b, c, -1), p["attn"]["wo"])
+    return mlp_residual(p, cfg, x), (k, v)
 
 
 def decode_step_rows(params: Params, cfg: ModelConfig, cache: Params,

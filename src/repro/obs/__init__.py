@@ -4,7 +4,11 @@ The observability subsystem the serving stack instruments against:
 
 * :mod:`repro.obs.tracer` — span tracer (ring buffer when enabled,
   zero-allocation no-op when disabled) recording every scheduler phase
-  and every backend dispatch lane;
+  and every backend dispatch lane; each live span is also a
+  ``repro.<name>`` event on the ``jax.profiler`` trace;
+* :mod:`repro.obs.scopes` — the named scopes of the paged step
+  (``kv_gather``, ``attn``, ``mlp``, ``unembed``, ``kv_write``) read back
+  from compiled HLO, so a device trace's ops say which part they are;
 * :mod:`repro.obs.perfetto` — trace-event JSON export for
   ui.perfetto.dev / chrome://tracing, plus the schema validator CI runs;
 * :mod:`repro.obs.metrics` — counters/gauges/histograms with p50/p99

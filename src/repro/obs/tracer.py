@@ -2,7 +2,7 @@
 
 The paper's methodology lives or dies on *where* time goes per dispatch
 (host submit vs device compute, §7.2); this tracer records that timeline
-for the whole serving stack with two non-negotiable properties:
+for the whole serving stack with three properties:
 
 * **Zero-allocation disabled fast path.**  ``Tracer.span(...)`` on a
   disabled tracer returns one shared ``_NullSpan`` singleton and records
@@ -13,16 +13,22 @@ for the whole serving stack with two non-negotiable properties:
   buffer; a run that outlives the buffer drops the OLDEST events (the
   ``dropped`` counter says how many) instead of growing without bound —
   a tracer you can leave on under production traffic.
+* **On the profiler's clock.**  A live span also enters
+  ``jax.profiler.TraceAnnotation("repro." + name)`` for its extent, so
+  under ``jax.profiler.trace`` the same span sits in the ``.xplane.pb``
+  beside the device's ops, and device idle time can be put down to what
+  the host was doing.  With no profiler session active the annotation
+  costs well under a microsecond.
 
 Events are plain ``SpanEvent`` records on named *tracks* ("scheduler",
 "slot3", "backend:F3" ...); ``repro.obs.perfetto`` maps tracks to
-Perfetto/chrome-tracing threads.  Three recording surfaces:
+Perfetto/chrome-tracing threads.  Recording surfaces:
 
 * ``with tracer.span("decode_cycle", track="scheduler"): ...`` — timed
-  context manager, nesting depth tracked per track;
-* ``tracer.add(name, ts, dur, ...)`` — retroactive span for an interval
-  the caller already measured (how backends log dispatch submits without
-  re-timing them);
+  context manager, nesting tracked per track;
+* ``tracer.open_span(track, cat)`` — the innermost span still open on a
+  track, whose ``tally`` adds counts into its args (how a backend's
+  dispatch accounting lands on the live dispatch span it opened);
 * ``tracer.instant(...)`` / ``tracer.counter(...)`` — point events
   (radix hit, COW fork, eviction) and counter samples.
 """
@@ -30,6 +36,11 @@ from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of every live span's name on the profiler's timeline
+PROFILER_PREFIX = "repro."
 
 
 class SpanEvent(NamedTuple):
@@ -61,8 +72,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Timed span: stamps entry/exit and pushes one "X" event."""
-    __slots__ = ("_tr", "name", "cat", "track", "args", "_t0", "_depth")
+    """Timed span: stamps entry/exit, pushes one "X" event, and holds a
+    profiler annotation of the same name open for its extent."""
+    __slots__ = ("_tr", "name", "cat", "track", "args", "_t0", "_stack",
+                 "_depth", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, track: str,
                  args: Optional[Dict[str, Any]]) -> None:
@@ -73,18 +86,28 @@ class _LiveSpan:
         self.args = args
 
     def __enter__(self) -> "_LiveSpan":
-        depths = self._tr._depth
-        self._depth = depths.get(self.track, 0)
-        depths[self.track] = self._depth + 1
+        self._stack = self._tr._open.setdefault(self.track, [])
+        self._depth = len(self._stack)
+        self._stack.append(self)
+        self._ann = TraceAnnotation(PROFILER_PREFIX + self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
-        self._tr._depth[self.track] = self._depth
+        self._ann.__exit__(*exc)
+        del self._stack[self._depth:]
         self._tr._push(SpanEvent(self.name, self.cat, self.track, self._t0,
                                  dur, "X", self._depth, self.args))
         return False
+
+    def tally(self, **counts: float) -> None:
+        """Add ``counts`` into this span's args (missing keys start at 0)."""
+        if self.args is None:
+            self.args = {}
+        for k, v in counts.items():
+            self.args[k] = self.args.get(k, 0) + v
 
 
 class Tracer:
@@ -98,7 +121,7 @@ class Tracer:
         self.dropped = 0
         self._buf: List[Optional[SpanEvent]] = []
         self._head = 0                      # next write index once full
-        self._depth: Dict[str, int] = {}
+        self._open: Dict[str, List[_LiveSpan]] = {}   # per track, outer first
 
     # -- recording -----------------------------------------------------
     def span(self, name: str, *, cat: str = "phase", track: str = "main",
@@ -108,21 +131,22 @@ class Tracer:
             return NULL_SPAN
         return _LiveSpan(self, name, cat, track, args or None)
 
-    def add(self, name: str, ts: float, dur: float, *,
-            cat: str = "dispatch", track: str = "main",
-            args: Optional[Dict[str, Any]] = None) -> None:
-        """Record an already-measured interval as a complete span."""
-        if not self.enabled:
-            return
-        self._push(SpanEvent(name, cat, track, ts, dur, "X",
-                             self._depth.get(track, 0), args))
+    def open_span(self, track: str, cat: Optional[str] = None
+                  ) -> Optional[_LiveSpan]:
+        """The innermost span still open on ``track`` (of category
+        ``cat``, if given), or None."""
+        for sp in reversed(self._open.get(track, ())):
+            if cat is None or sp.cat == cat:
+                return sp
+        return None
 
     def instant(self, name: str, *, cat: str = "event",
                 track: str = "main", **args) -> None:
         if not self.enabled:
             return
         self._push(SpanEvent(name, cat, track, time.perf_counter(), 0.0,
-                             "i", self._depth.get(track, 0), args or None))
+                             "i", len(self._open.get(track, ())),
+                             args or None))
 
     def counter(self, name: str, value: float, *, track: str = "main"
                 ) -> None:
@@ -153,14 +177,15 @@ class Tracer:
         self._buf = []
         self._head = 0
         self.dropped = 0
-        self._depth = {}
+        self._open = {}
 
     # -- derived accounting -------------------------------------------
     def dispatch_total(self) -> int:
         """Sum of ``args["dispatches"]`` over dispatch-lane spans — the
         trace-derived dispatch count CI checks against the backend's
-        ``dispatch_stats()`` delta (both flow through ``_record``, so
-        the two MUST agree exactly)."""
+        ``dispatch_stats()`` delta (``_record`` feeds both, tallying each
+        count onto the live dispatch span its backend call opened, so the
+        two MUST agree exactly)."""
         return sum(ev.args.get("dispatches", 0)
                    for ev in self.events()
                    if ev.cat == "dispatch" and ev.args)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import RunStats
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_SPAN, NULL_TRACER
 
 State = Dict[str, Any]
 BatchState = Dict[str, Any]     # opaque slot-pool state (continuous batching)
@@ -427,10 +427,12 @@ class ExecutionBackend(abc.ABC):
         # through the extend path so first-token logits exist
         matched, blocks = (radix.match(toks[:-1]) if radix is not None
                            else (0, []))
-        copies = pg.adopt_prefix(slot, matched, blocks)
-        if copies:
-            self._record(RunStats(wall_s=0.0, dispatches=copies, shape_ops=0,
-                                  sync_mode="none"), op="cow_adopt")
+        with self._dispatch_span("cow_adopt"):
+            copies = pg.adopt_prefix(slot, matched, blocks)
+            if copies:
+                self._record(RunStats(wall_s=0.0, dispatches=copies,
+                                      shape_ops=0, sync_mode="none"),
+                             op="cow_adopt")
         bstate["meta"][slot] = {"prompt": toks, "cursor": matched}
         return PagedAdmit(cached=matched, total=len(toks))
 
@@ -462,24 +464,27 @@ class ExecutionBackend(abc.ABC):
         next_token)`` runs one extend step and owns its arena adoption and
         dispatch accounting — ``_extend_with_jit`` wraps the common
         array-signature jit (model/dist), the graph backend supplies its
-        engine-driven executor.
+        engine-driven executor.  The whole call is one
+        ``dispatch:prefill_chunk`` span.
         """
-        pg = bstate["paged"]
-        meta = bstate["meta"][slot]
-        toks, cur = meta["prompt"], meta["cursor"]
-        plen = len(toks)
-        c = bstate["chunk"] or (plen - cur)
-        valid = min(c, plen - cur)
-        buf = np.zeros((1, c), np.int32)
-        buf[0, :valid] = toks[cur:cur + valid]
-        copies = pg.ensure_writable(slot, cur, cur + c)
-        logits, nxt = run_extend(bstate, slot, buf, cur, valid, copies)
-        meta["cursor"] = cur + valid
-        pg.pos[slot] = cur + valid
-        if meta["cursor"] < plen:
-            return None
-        self._finish_paged_prefill(bstate, slot)
-        return StepOutput(logits, nxt)
+        with self._dispatch_span("prefill_chunk"):
+            pg = bstate["paged"]
+            meta = bstate["meta"][slot]
+            toks, cur = meta["prompt"], meta["cursor"]
+            plen = len(toks)
+            c = bstate["chunk"] or (plen - cur)
+            valid = min(c, plen - cur)
+            buf = np.zeros((1, c), np.int32)
+            buf[0, :valid] = toks[cur:cur + valid]
+            with self._phase("prepare"):
+                copies = pg.ensure_writable(slot, cur, cur + c)
+            logits, nxt = run_extend(bstate, slot, buf, cur, valid, copies)
+            meta["cursor"] = cur + valid
+            pg.pos[slot] = cur + valid
+            if meta["cursor"] < plen:
+                return None
+            self._finish_paged_prefill(bstate, slot)
+            return StepOutput(logits, nxt)
 
     def _extend_with_jit(self, fn):
         """Executor for ``_prefill_chunk_with`` over the shared jitted
@@ -488,16 +493,19 @@ class ExecutionBackend(abc.ABC):
         single-device extend or the dist pipeline extend)."""
         def run(bstate, slot, buf, cur, valid, copies):
             pg = bstate["paged"]
-            t0 = time.perf_counter()
-            ak, av, logits, nxt = fn(
-                self.params, pg.pool.arena_k, pg.pool.arena_v,
-                device_snapshot(pg.table[slot:slot + 1]), jnp.int32(cur),
-                jnp.int32(valid), jnp.asarray(buf))
+            t0 = time.perf_counter()     # enqueue_s: upload + call
+            with self._phase("upload"):
+                args = (device_snapshot(pg.table[slot:slot + 1]),
+                        jnp.int32(cur), jnp.int32(valid), jnp.asarray(buf))
+            with self._phase("enqueue"):
+                ak, av, logits, nxt = fn(self.params, pg.pool.arena_k,
+                                         pg.pool.arena_v, *args)
             enq = time.perf_counter() - t0
             self._record(RunStats(wall_s=enq, dispatches=1 + copies,
                                   shape_ops=0, sync_mode="none",
                                   enqueue_s=enq), op="prefill_chunk")
-            pg.pool.set_arena(ak, av)
+            with self._phase("adopt"):
+                pg.pool.set_arena(ak, av)
             return logits, nxt
         return run
 
@@ -544,9 +552,10 @@ class ExecutionBackend(abc.ABC):
             raise ValueError("swap_out_paged needs the paged batch state "
                              "from alloc_slots_paged")
         pg = bstate["paged"]
-        chain = pg.swap_out(slot)
-        self._record(RunStats(wall_s=0.0, dispatches=0, shape_ops=0,
-                              sync_mode="none"), op="swap_out")
+        with self._dispatch_span("swap_out"):
+            chain = pg.swap_out(slot)
+            self._record(RunStats(wall_s=0.0, dispatches=0, shape_ops=0,
+                                  sync_mode="none"), op="swap_out")
         return {"chain": chain, "meta": bstate["meta"].pop(slot, None)}
 
     def swap_in_paged(self, bstate: BatchState, swap: Dict[str, Any],
@@ -567,11 +576,13 @@ class ExecutionBackend(abc.ABC):
         cursor) is restored so decode resumes exactly where it stopped.
         """
         pg = bstate["paged"]
-        t0 = time.perf_counter()
-        slot, uploads = pg.swap_in(swap["chain"], slot)
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=uploads, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq), op="swap_in")
+        with self._dispatch_span("swap_in"):
+            t0 = time.perf_counter()
+            slot, uploads = pg.swap_in(swap["chain"], slot)
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=uploads,
+                                  shape_ops=0, sync_mode="none",
+                                  enqueue_s=enq), op="swap_in")
         if swap["meta"] is not None:
             bstate["meta"][slot] = swap["meta"]
         return slot
@@ -633,23 +644,47 @@ class ExecutionBackend(abc.ABC):
     def reset_stats(self) -> None:
         self._stats = DispatchStats()
 
+    def _lane(self) -> str:
+        return f"backend:{self.capabilities.name}"
+
+    def _dispatch_span(self, op: str):
+        """The live span one backend call opens on its dispatch lane
+        (``repro.dispatch:<op>`` on the profiler); every ``_record`` inside
+        it tallies its counts onto it."""
+        tr = self.tracer
+        if not tr.enabled:
+            return NULL_SPAN
+        return tr.span(f"dispatch:{op}", cat="dispatch", track=self._lane(),
+                       op=op)
+
+    def _phase(self, name: str):
+        """A span on the dispatch lane for one part of a backend call:
+        ``prepare`` (COW), ``upload`` (host metadata to the device),
+        ``enqueue`` (the jitted call until it returns), ``adopt`` (taking
+        the new arena)."""
+        tr = self.tracer
+        if not tr.enabled:
+            return NULL_SPAN
+        return tr.span(name, track=self._lane())
+
     def _record(self, rs: RunStats, op: str = "dispatch") -> None:
         """The SINGLE dispatch-accounting choke point: every backend
         dispatch flows through here, updating ``dispatch_stats()`` AND —
-        when a tracer is attached — emitting one span on the backend's
-        dispatch lane whose ``dispatches`` arg carries the same count.
-        Trace-derived totals therefore equal the stats delta exactly
-        (the CI obs gate asserts it)."""
+        when a tracer is attached — tallying the same count onto the live
+        ``dispatch:<op>`` span the calling backend method opened
+        (``_dispatch_span``).  Trace-derived totals therefore equal the
+        stats delta exactly (the CI obs gate asserts it)."""
         self._stats.add(rs)
         tr = self.tracer
         if tr.enabled:
-            now = time.perf_counter()
-            tr.add(f"dispatch:{op}", now - rs.wall_s, rs.wall_s,
-                   cat="dispatch",
-                   track=f"backend:{self.capabilities.name}",
-                   args={"op": op, "dispatches": rs.dispatches,
-                         "enqueue_us": round(1e6 * rs.enqueue_s, 1),
-                         "sync_us": round(1e6 * rs.sync_s, 1)})
+            sp = tr.open_span(self._lane(), cat="dispatch")
+            if sp is None:
+                raise RuntimeError(
+                    f"dispatch {op!r} recorded outside a dispatch span: "
+                    "open one with _dispatch_span around the backend call")
+            sp.tally(dispatches=rs.dispatches,
+                     enqueue_us=round(1e6 * rs.enqueue_s, 1),
+                     sync_us=round(1e6 * rs.sync_s, 1))
 
 
 # ---------------------------------------------------------------------------

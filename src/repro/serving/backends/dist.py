@@ -378,31 +378,33 @@ class DistBackend(ExecutionBackend):
         if "paged" not in bstate:
             return super().decode_batch(bstate, tokens, slots)
         pg = bstate["paged"]
-        copies = 0
-        for s in slots:
-            copies += pg.ensure_writable(s, int(pg.pos[s]),
-                                         int(pg.pos[s]) + 1)
-        t0 = time.perf_counter()
-        ak, av, logits, nxt = self._jit_decode_paged(
-            self.params, pg.pool.arena_k, pg.pool.arena_v,
-            device_snapshot(pg.table), device_snapshot(pg.pos),
-            jnp.asarray(tokens, jnp.int32))
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1 + copies, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq),
-                     op="decode_batch")
-        pg.pool.set_arena(ak, av)
-        pg.advance(slots)
+        with self._dispatch_span("decode_batch"):
+            copies = 0
+            for s in slots:
+                copies += pg.ensure_writable(s, int(pg.pos[s]),
+                                             int(pg.pos[s]) + 1)
+            t0 = time.perf_counter()
+            ak, av, logits, nxt = self._jit_decode_paged(
+                self.params, pg.pool.arena_k, pg.pool.arena_v,
+                device_snapshot(pg.table), device_snapshot(pg.pos),
+                jnp.asarray(tokens, jnp.int32))
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=1 + copies,
+                                  shape_ops=0, sync_mode="none",
+                                  enqueue_s=enq), op="decode_batch")
+            pg.pool.set_arena(ak, av)
+            pg.advance(slots)
         return bstate, StepOutput(logits, nxt)
 
     # ------------------------------------------------------------------
     def _run(self, fn, *args, op: str = "dispatch"
              ) -> Tuple[object, StepOutput]:
-        t0 = time.perf_counter()
-        cache, logits, nxt = fn(*args)
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq), op=op)
+        with self._dispatch_span(op):
+            t0 = time.perf_counter()
+            cache, logits, nxt = fn(*args)
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
+                                  sync_mode="none", enqueue_s=enq), op=op)
         return cache, StepOutput(logits, nxt)
 
     def prefill(self, tokens) -> Tuple[State, StepOutput]:

@@ -104,8 +104,9 @@ class GraphBackend(ExecutionBackend):
         b, plen = tokens.shape
         assert b == self.batch, f"backend built for batch={self.batch}, got {b}"
         eng = self._prefill_engine(plen)
-        out, rs = eng.run({"tokens": tokens}, record_timeline=True)
-        self._record(rs, op="prefill")
+        with self._dispatch_span("prefill"):
+            out, rs = eng.run({"tokens": tokens}, record_timeline=True)
+            self._record(rs, op="prefill")
         cache = load_prefix(
             empty_graph_cache(self.cfg, b, self.max_len), out,
             self.cfg.num_layers)
@@ -116,8 +117,9 @@ class GraphBackend(ExecutionBackend):
         inputs = dict(state["cache"])
         inputs["tokens"] = jnp.asarray(tok, jnp.int32)
         inputs["pos"] = jnp.int32(state["pos"])
-        out, rs = self._decode_engine.run(inputs, record_timeline=True)
-        self._record(rs, op="decode")
+        with self._dispatch_span("decode"):
+            out, rs = self._decode_engine.run(inputs, record_timeline=True)
+            self._record(rs, op="decode")
         cache = {}
         for l in range(self.cfg.num_layers):
             cache[f"k_cache_{l}"] = out[f"k_cache_{l}"]
@@ -176,8 +178,9 @@ class GraphBackend(ExecutionBackend):
         inputs = dict(kvp.tree)
         inputs["tokens"] = jnp.asarray(tokens, jnp.int32)
         inputs["pos"] = device_snapshot(kvp.pos)
-        out, rs = eng.run(inputs, record_timeline=True)
-        self._record(rs, op="decode_batch")
+        with self._dispatch_span("decode_batch"):
+            out, rs = eng.run(inputs, record_timeline=True)
+            self._record(rs, op="decode_batch")
         kvp.tree = {f"{c}_cache_{l}": out[f"{c}_cache_{l}"]
                     for l in range(self.cfg.num_layers) for c in ("k", "v")}
         kvp.advance(slots)
@@ -264,22 +267,24 @@ class GraphBackend(ExecutionBackend):
         """The paged cycle: same dispatch stream as the dense slot_pos
         cycle, read/written through per-slot block tables."""
         pg = bstate["paged"]
-        copies = 0
-        for s in slots:
-            copies += pg.ensure_writable(s, int(pg.pos[s]),
-                                         int(pg.pos[s]) + 1)
-        if copies:
-            self._record(RunStats(wall_s=0.0, dispatches=copies, shape_ops=0,
-                                  sync_mode="none"), op="cow_copy")
-        eng = bstate["decode_eng"]
-        inputs = dict(pg.pool.tree)
-        inputs["tokens"] = jnp.asarray(tokens, jnp.int32)
-        inputs["pos"] = device_snapshot(pg.pos)
-        inputs["block_table"] = device_snapshot(pg.table)
-        out, rs = eng.run(inputs, record_timeline=True)
-        self._record(rs, op="decode_batch")
-        pg.pool.set_tree(out)
-        pg.advance(slots)
+        with self._dispatch_span("decode_batch"):
+            copies = 0
+            for s in slots:
+                copies += pg.ensure_writable(s, int(pg.pos[s]),
+                                             int(pg.pos[s]) + 1)
+            if copies:
+                self._record(RunStats(wall_s=0.0, dispatches=copies,
+                                      shape_ops=0, sync_mode="none"),
+                             op="cow_copy")
+            eng = bstate["decode_eng"]
+            inputs = dict(pg.pool.tree)
+            inputs["tokens"] = jnp.asarray(tokens, jnp.int32)
+            inputs["pos"] = device_snapshot(pg.pos)
+            inputs["block_table"] = device_snapshot(pg.table)
+            out, rs = eng.run(inputs, record_timeline=True)
+            self._record(rs, op="decode_batch")
+            pg.pool.set_tree(out)
+            pg.advance(slots)
         return bstate, StepOutput(out["logits"], out["next_token"])
 
     # -- multi-step decode capture (the host-sync-free super-step) --------
@@ -317,10 +322,11 @@ class GraphBackend(ExecutionBackend):
         kvp: SlotKVCache = bstate["kv"]
         eng = self._multi_engine(
             self._batched_engine(bstate["num_slots"]).graph, horizon)
-        caches, toks, valid, steps, rs = eng.run(
-            kvp.tree, tokens, device_snapshot(kvp.pos),
-            stop_table=stop_table)
-        self._record(rs, op="decode_multi")
+        with self._dispatch_span("decode_multi"):
+            caches, toks, valid, steps, rs = eng.run(
+                kvp.tree, tokens, device_snapshot(kvp.pos),
+                stop_table=stop_table)
+            self._record(rs, op="decode_multi")
         kvp.tree = dict(caches)
         kvp.pos[list(slots)] += horizon
         return bstate, MultiStepOutput(toks, valid, steps)
@@ -334,19 +340,21 @@ class GraphBackend(ExecutionBackend):
         front — the same accounting as ``horizon`` single steps, paid in
         one host pass."""
         pg = bstate["paged"]
-        copies = 0
-        for s in slots:
-            copies += pg.ensure_writable(s, int(pg.pos[s]),
-                                         int(pg.pos[s]) + horizon)
-        if copies:
-            self._record(RunStats(wall_s=0.0, dispatches=copies, shape_ops=0,
-                                  sync_mode="none"), op="cow_copy")
-        eng = self._multi_engine(bstate["decode_eng"].graph, horizon)
-        caches, toks, valid, steps, rs = eng.run(
-            pg.pool.tree, tokens, device_snapshot(pg.pos),
-            stop_table=stop_table,
-            static={"block_table": device_snapshot(pg.table)})
-        self._record(rs, op="decode_multi")
+        with self._dispatch_span("decode_multi"):
+            copies = 0
+            for s in slots:
+                copies += pg.ensure_writable(s, int(pg.pos[s]),
+                                             int(pg.pos[s]) + horizon)
+            if copies:
+                self._record(RunStats(wall_s=0.0, dispatches=copies,
+                                      shape_ops=0, sync_mode="none"),
+                             op="cow_copy")
+            eng = self._multi_engine(bstate["decode_eng"].graph, horizon)
+            caches, toks, valid, steps, rs = eng.run(
+                pg.pool.tree, tokens, device_snapshot(pg.pos),
+                stop_table=stop_table,
+                static={"block_table": device_snapshot(pg.table)})
+            self._record(rs, op="decode_multi")
         pg.pool.set_tree(caches)
         pg.pos[list(slots)] += horizon
         return bstate, MultiStepOutput(toks, valid, steps)

@@ -19,7 +19,7 @@ the family's own ``decode_step_rows``; those dispatches are recorded as
 from __future__ import annotations
 
 import time
-from typing import Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,9 +81,15 @@ class ModelBackend(ExecutionBackend):
         self._jit_prefill = jax.jit(_prefill)
         self._jit_decode = jax.jit(_decode)
         self._jit_decode_rows = jax.jit(_decode_rows, donate_argnums=(1, 2))
-        self._jit_decode_paged = jax.jit(_decode_paged, donate_argnums=(1, 2))
-        self._jit_extend_paged = jax.jit(_extend_paged, donate_argnums=(1, 2))
-        self._jit_verify_paged = jax.jit(_verify_paged, donate_argnums=(1, 2))
+        # the paged programs note the shapes they run at while a tracer
+        # is attached, so ``compiled_programs`` can name their ops
+        self._ran: Dict[Tuple, Tuple] = {}
+        self._jit_decode_paged = self._noting(
+            jax.jit(_decode_paged, donate_argnums=(1, 2)))
+        self._jit_extend_paged = self._noting(
+            jax.jit(_extend_paged, donate_argnums=(1, 2)))
+        self._jit_verify_paged = self._noting(
+            jax.jit(_verify_paged, donate_argnums=(1, 2)))
         self._jit_decode_recurrent = jax.jit(_decode_recurrent,
                                              donate_argnums=(1,))
         batchable = self.cfg.family in ("dense", "moe")
@@ -99,13 +105,46 @@ class ModelBackend(ExecutionBackend):
             state_kind="recurrent" if self._recurrent else "kv")
 
     # ------------------------------------------------------------------
+    def _noting(self, fn):
+        """``fn`` as it is called, noting the shapes of its arguments
+        after the parameters while a tracer is attached."""
+        def call(params, *args):
+            if self.tracer.enabled:
+                key = (fn,) + tuple(a.shape for a in args)
+                if key not in self._ran:
+                    self._ran[key] = (fn, tuple(
+                        jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=a.sharding)
+                        for a in args))
+            return fn(params, *args)
+        return call
+
+    def compiled_programs(self) -> Dict[str, List[str]]:
+        """The compiled HLO text of each paged program this backend ran
+        while a tracer was attached, one per shape it ran at, keyed by the
+        program's name as the profiler prints it (``jit__decode_paged``,
+        ``jit__extend_paged``, ``jit__verify_paged``).
+
+        The text names every instruction as a device trace names its
+        ops, so ``repro.obs.scopes.op_scopes`` over it says which part of
+        the step (``kv_gather``, ``attn``, ``mlp``, ...) each op of a
+        trace belongs to.  Compiles once per call and shape."""
+        out: Dict[str, List[str]] = {}
+        for fn, shapes in self._ran.values():
+            text = fn.lower(self.params, *shapes).compile().as_text()
+            name = text.split(None, 2)[1].rstrip(",")  # "HloModule <name>,"
+            out.setdefault(name, []).append(text)
+        return out
+
     def _run(self, fn, *args, op: str = "dispatch"
              ) -> Tuple[object, StepOutput]:
-        t0 = time.perf_counter()
-        cache, logits, nxt = fn(*args)
-        enq = time.perf_counter() - t0  # async call until handle return
-        self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq), op=op)
+        with self._dispatch_span(op):
+            t0 = time.perf_counter()
+            with self._phase("enqueue"):
+                cache, logits, nxt = fn(*args)
+            enq = time.perf_counter() - t0  # async call until handle return
+            self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
+                                  sync_mode="none", enqueue_s=enq), op=op)
         return cache, StepOutput(logits, nxt)
 
     def prefill(self, tokens) -> Tuple[State, StepOutput]:
@@ -169,16 +208,17 @@ class ModelBackend(ExecutionBackend):
         if "kv" not in bstate:
             return super().decode_batch(bstate, tokens, slots)
         kv: SlotKVCache = bstate["kv"]
-        t0 = time.perf_counter()
-        k, v, logits, nxt = self._jit_decode_rows(
-            self.params, kv.tree["k"], kv.tree["v"],
-            device_snapshot(kv.pos), jnp.asarray(tokens, jnp.int32))
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq),
-                     op="decode_batch")
-        kv.tree = {"k": k, "v": v}
-        kv.advance(slots)
+        with self._dispatch_span("decode_batch"):
+            t0 = time.perf_counter()
+            k, v, logits, nxt = self._jit_decode_rows(
+                self.params, kv.tree["k"], kv.tree["v"],
+                device_snapshot(kv.pos), jnp.asarray(tokens, jnp.int32))
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
+                                  sync_mode="none", enqueue_s=enq),
+                         op="decode_batch")
+            kv.tree = {"k": k, "v": v}
+            kv.advance(slots)
         return bstate, StepOutput(logits, nxt)
 
     def _decode_batch_recurrent(self, bstate: BatchState, tokens,
@@ -187,16 +227,17 @@ class ModelBackend(ExecutionBackend):
         """ONE dispatch advances every recurrent slot's constant-size
         state at its own per-row position (``op="decode_recurrent"``)."""
         rs: RecurrentStateCache = bstate["rstate"]
-        t0 = time.perf_counter()
-        tree, logits, nxt = self._jit_decode_recurrent(
-            self.params, rs.tree, device_snapshot(rs.pos),
-            jnp.asarray(tokens, jnp.int32))
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq),
-                     op="decode_recurrent")
-        rs.tree = tree
-        rs.advance(slots)
+        with self._dispatch_span("decode_recurrent"):
+            t0 = time.perf_counter()
+            tree, logits, nxt = self._jit_decode_recurrent(
+                self.params, rs.tree, device_snapshot(rs.pos),
+                jnp.asarray(tokens, jnp.int32))
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
+                                  sync_mode="none", enqueue_s=enq),
+                         op="decode_recurrent")
+            rs.tree = tree
+            rs.advance(slots)
         return bstate, StepOutput(logits, nxt)
 
     # -- paged KV: block pool + radix prefix cache + chunked prefill ------
@@ -220,22 +261,32 @@ class ModelBackend(ExecutionBackend):
     def _decode_batch_paged(self, bstate: BatchState, tokens,
                             slots: Sequence[int]
                             ) -> Tuple[BatchState, StepOutput]:
-        pg = bstate["paged"]
-        copies = 0
-        for s in slots:
-            copies += pg.ensure_writable(s, int(pg.pos[s]), int(pg.pos[s]) + 1)
+        with self._dispatch_span("decode_batch"):
+            pg = bstate["paged"]
+            with self._phase("prepare"):
+                copies = sum(pg.ensure_writable(s, int(pg.pos[s]),
+                                                int(pg.pos[s]) + 1)
+                             for s in slots)
+            ak, av, logits, nxt = self._enqueue_paged(
+                self._jit_decode_paged, pg, tokens, copies, "decode_batch")
+            with self._phase("adopt"):
+                pg.pool.set_arena(ak, av)
+                pg.advance(slots)
+        return bstate, StepOutput(logits, nxt)
+
+    def _enqueue_paged(self, fn, pg, tokens, copies: int, op: str):
+        """Upload the block table, positions and tokens, then make the
+        jitted paged call; ``enqueue_s`` times both, as it always has."""
         t0 = time.perf_counter()
-        ak, av, logits, nxt = self._jit_decode_paged(
-            self.params, pg.pool.arena_k, pg.pool.arena_v,
-            device_snapshot(pg.table), device_snapshot(pg.pos),
-            jnp.asarray(tokens, jnp.int32))
+        with self._phase("upload"):
+            args = (device_snapshot(pg.table), device_snapshot(pg.pos),
+                    jnp.asarray(tokens, jnp.int32))
+        with self._phase("enqueue"):
+            out = fn(self.params, pg.pool.arena_k, pg.pool.arena_v, *args)
         enq = time.perf_counter() - t0
         self._record(RunStats(wall_s=enq, dispatches=1 + copies, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq),
-                     op="decode_batch")
-        pg.pool.set_arena(ak, av)
-        pg.advance(slots)
-        return bstate, StepOutput(logits, nxt)
+                              sync_mode="none", enqueue_s=enq), op=op)
+        return out
 
     def verify_paged(self, bstate: BatchState, tokens,
                      slots: Sequence[int], spans
@@ -245,18 +296,14 @@ class ModelBackend(ExecutionBackend):
         ``pos`` — the scheduler commits the accepted prefix through the
         slot-fork API (rollback = pos rewind, zero KV copies)."""
         self.capabilities.require("speculative")
-        pg = bstate["paged"]
-        copies = 0
-        for s, span in zip(slots, spans):
-            copies += pg.ensure_writable(s, int(pg.pos[s]),
-                                         int(pg.pos[s]) + max(int(span), 1))
-        t0 = time.perf_counter()
-        ak, av, logits, nxt = self._jit_verify_paged(
-            self.params, pg.pool.arena_k, pg.pool.arena_v,
-            device_snapshot(pg.table), device_snapshot(pg.pos),
-            jnp.asarray(tokens, jnp.int32))
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1 + copies, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq), op="verify")
-        pg.pool.set_arena(ak, av)
+        with self._dispatch_span("verify"):
+            pg = bstate["paged"]
+            with self._phase("prepare"):
+                copies = sum(pg.ensure_writable(
+                    s, int(pg.pos[s]), int(pg.pos[s]) + max(int(span), 1))
+                    for s, span in zip(slots, spans))
+            ak, av, logits, nxt = self._enqueue_paged(
+                self._jit_verify_paged, pg, tokens, copies, "verify")
+            with self._phase("adopt"):
+                pg.pool.set_arena(ak, av)
         return bstate, StepOutput(logits, nxt)

@@ -66,12 +66,13 @@ class OnDeviceBackend(ModelBackend):
 
         rng = jax.random.PRNGKey(0) if rng is None else rng
         keys = jax.random.split(rng, n_new)
-        t0 = time.perf_counter()
-        toks = self._ondevice(self.params, state["cache"],
-                              jnp.asarray(first_tok, jnp.int32), keys,
-                              n_new, sampler)
-        enq = time.perf_counter() - t0
-        self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
-                              sync_mode="none", enqueue_s=enq),
-                     op="ondevice_loop")
+        with self._dispatch_span("ondevice_loop"):
+            t0 = time.perf_counter()
+            toks = self._ondevice(self.params, state["cache"],
+                                  jnp.asarray(first_tok, jnp.int32), keys,
+                                  n_new, sampler)
+            enq = time.perf_counter() - t0
+            self._record(RunStats(wall_s=enq, dispatches=1, shape_ops=0,
+                                  sync_mode="none", enqueue_s=enq),
+                         op="ondevice_loop")
         return toks

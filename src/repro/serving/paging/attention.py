@@ -42,10 +42,43 @@ from repro.models import transformer
 def gather_blocks(arena: jax.Array, table: jax.Array) -> jax.Array:
     """(N, L, Bs, KV, hd) arena + (S, W) block table → (L, S, W·Bs, KV, hd)
     dense per-layer view, position-compatible with the dense cache."""
-    g = arena[table]                               # (S, W, L, Bs, KV, hd)
-    s, w, nl, bs = g.shape[:4]
-    g = jnp.moveaxis(g, 2, 0)                      # (L, S, W, Bs, KV, hd)
-    return g.reshape(nl, s, w * bs, *g.shape[4:])
+    with jax.named_scope("kv_gather"):
+        g = arena[table]                           # (S, W, L, Bs, KV, hd)
+        s, w, nl, bs = g.shape[:4]
+        g = jnp.moveaxis(g, 2, 0)                  # (L, S, W, Bs, KV, hd)
+        return g.reshape(nl, s, w * bs, *g.shape[4:])
+
+
+def _unembed_argmax(params, cfg: ModelConfig, x: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Final norm, logits and greedy token, under the ``unembed`` scope."""
+    with jax.named_scope("unembed"):
+        logits = transformer.unembed(params, cfg, x)
+        return logits, jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def _write_kv(arena_k: jax.Array, arena_v: jax.Array, table: jax.Array,
+              positions: jax.Array, knew: jax.Array, vnew: jax.Array
+              ) -> Tuple[jax.Array, jax.Array]:
+    """Scatter the new K/V into the arena blocks that ``table`` maps
+    ``positions`` to, under the ``kv_write`` scope.
+
+    ``table`` is indexed by ``(rows, positions // block_size)``;
+    ``knew``/``vnew`` are (L, *positions.shape, KV, hd), layer first as
+    the layer scan stacks them.  The advanced indices (block, offset) are
+    separated by the layer slice, so they move to the front and the
+    layer axis goes after them."""
+    with jax.named_scope("kv_write"):
+        bs = arena_k.shape[2]
+        rows = jnp.arange(table.shape[0]).reshape(
+            (-1,) + (1,) * (positions.ndim - 1))
+        bids = table[rows, positions // bs]
+        offs = positions % bs
+
+        def put(arena, new):
+            new = jnp.moveaxis(new, 0, positions.ndim)
+            return arena.at[bids, :, offs].set(new.astype(arena.dtype))
+        return put(arena_k, knew), put(arena_v, vnew)
 
 
 def decode_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
@@ -68,18 +101,10 @@ def decode_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
                                             emit_cache=False)
 
     x, (knew, vnew) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
-    logits = transformer.unembed(params, cfg, x)
-    bs = arena_k.shape[2]
-    rows = jnp.arange(tokens.shape[0])
-    bids = table[rows, pos // bs]
-    offs = pos % bs
-    # knew (L, S, KV, hd) → (S, L, KV, hd): each slot's new token lands in
-    # its current block, which ensure_writable made exclusively ours
-    arena_k = arena_k.at[bids, :, offs].set(
-        jnp.moveaxis(knew, 0, 1).astype(arena_k.dtype))
-    arena_v = arena_v.at[bids, :, offs].set(
-        jnp.moveaxis(vnew, 0, 1).astype(arena_v.dtype))
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+    logits, nxt = _unembed_argmax(params, cfg, x)
+    # each slot's new token lands in its current block, which
+    # ensure_writable made exclusively ours
+    arena_k, arena_v = _write_kv(arena_k, arena_v, table, pos, knew, vnew)
     return arena_k, arena_v, logits, nxt
 
 
@@ -114,19 +139,10 @@ def verify_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
                                         positions)
 
     x, (kch, vch) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
-    logits = transformer.unembed(params, cfg, x)
-    bs = arena_k.shape[2]
-    rows = jnp.arange(tokens.shape[0])
-    bids = table[rows[:, None], positions // bs]   # (S, C)
-    offs = positions % bs
-    # kch (L, S, C, KV, hd) → (S, C, L, KV, hd): advanced indices (bids,
-    # offs) are separated by the layer slice, so they move to the front —
-    # the same trick decode_step_paged uses, batched over the span axis
-    arena_k = arena_k.at[bids, :, offs].set(
-        jnp.moveaxis(kch, 0, 2).astype(arena_k.dtype))
-    arena_v = arena_v.at[bids, :, offs].set(
-        jnp.moveaxis(vch, 0, 2).astype(arena_v.dtype))
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+    logits, nxt = _unembed_argmax(params, cfg, x)
+    # the same scatter decode_step_paged makes, batched over the span axis
+    arena_k, arena_v = _write_kv(arena_k, arena_v, table, positions, kch,
+                                 vch)
     return arena_k, arena_v, logits, nxt
 
 
@@ -157,16 +173,9 @@ def extend_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
 
     x, (kch, vch) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
     x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
-    logits = transformer.unembed(params, cfg, x_last)
-    bs = arena_k.shape[2]
-    idx = pos0 + jnp.arange(c)
-    bids = table_row[0, idx // bs]
-    offs = idx % bs
-    # kch (L, 1, C, KV, hd) → (C, L, KV, hd); padded positions land in
-    # writable blocks and are overwritten before anything can attend them
-    arena_k = arena_k.at[bids, :, offs].set(
-        jnp.moveaxis(kch[:, 0], 0, 1).astype(arena_k.dtype))
-    arena_v = arena_v.at[bids, :, offs].set(
-        jnp.moveaxis(vch[:, 0], 0, 1).astype(arena_v.dtype))
-    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+    logits, nxt = _unembed_argmax(params, cfg, x_last)
+    # padded positions land in writable blocks and are overwritten before
+    # anything can attend them
+    arena_k, arena_v = _write_kv(arena_k, arena_v, table_row, positions,
+                                 kch[:, 0], vch[:, 0])
     return arena_k, arena_v, logits, nxt

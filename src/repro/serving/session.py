@@ -892,14 +892,19 @@ class Scheduler:
                 st.kv_bytes_allocated = kv.bytes_allocated
             st.kv_bytes_live_peak = max(st.kv_bytes_live_peak, kv.bytes_live)
 
+    def _cycle_span(self, st: SchedulerStats, occupancy: int, **args):
+        """One ``decode_cycle`` span: the scheduler's host work from
+        issuing a cycle's dispatch to retiring the tokens read back in the
+        same iteration (issue N+1, retire N when the readback is
+        deferred).  Exactly one per ``st.cycles``."""
+        return self.tracer.span("decode_cycle", track="scheduler",
+                                cycle=st.cycles, occupancy=occupancy, **args)
+
     def _issue_cycle(self, bstate, active: Dict[int, "_Active"],
                      st: SchedulerStats, tokens):
         """ONE batched decode dispatch for every active slot."""
         slots = tuple(sorted(active))
-        with self.tracer.span("decode_cycle", track="scheduler",
-                              cycle=st.cycles, occupancy=len(slots)):
-            bstate, out = self.session.backend.decode_batch(bstate, tokens,
-                                                            slots)
+        bstate, out = self.session.backend.decode_batch(bstate, tokens, slots)
         st.cycles += 1
         st.occupancy_sum += len(slots)
         self._track_kv(bstate, st)
@@ -926,14 +931,13 @@ class Scheduler:
         """Read a cycle's tokens back and feed each slot its row."""
         backend = self.session.backend
         tr = self.tracer
-        t0 = time.perf_counter()
-        # one host readback per CYCLE (not per slot) in the greedy
-        # token-readback regime: a (num_slots,) int32 vector
-        nxt = (np.asarray(out.next_token, np.int32)
-               if out.next_token is not None else None)
-        dt = time.perf_counter() - t0
-        tr.add("readback", t0, dt, cat="phase", track="scheduler",
-               args={"overlapped": overlapped})
+        with tr.span("readback", track="scheduler", overlapped=overlapped):
+            t0 = time.perf_counter()
+            # one host readback per CYCLE (not per slot) in the greedy
+            # token-readback regime: a (num_slots,) int32 vector
+            nxt = (np.asarray(out.next_token, np.int32)
+                   if out.next_token is not None else None)
+            dt = time.perf_counter() - t0
         if overlapped:
             st.overlap_readback_s += dt
         else:
@@ -976,17 +980,15 @@ class Scheduler:
                and self._async_safe(active)
                and all(len(active[s].tokens) + 1
                        < active[s].req.max_new_tokens for s in slots)):
-            with self.tracer.span("decode_cycle", track="scheduler",
-                                  cycle=st.cycles, occupancy=len(slots),
-                                  overlapped=True):
+            with self._cycle_span(st, len(slots), overlapped=True):
                 bstate, out_next = backend.decode_batch(bstate,
                                                         out.next_token, slots)
-            st.cycles += 1
-            st.occupancy_sum += len(slots)
-            st.overlap_cycles += 1
-            self._track_kv(bstate, st)
-            bstate = self._retire_cycle(out, slots, active, results, bstate,
-                                        st, overlapped=True)
+                st.cycles += 1
+                st.occupancy_sum += len(slots)
+                st.overlap_cycles += 1
+                self._track_kv(bstate, st)
+                bstate = self._retire_cycle(out, slots, active, results,
+                                            bstate, st, overlapped=True)
             out = out_next
         return self._retire_cycle(out, slots, active, results, bstate, st,
                                   overlapped=False)
@@ -1032,13 +1034,8 @@ class Scheduler:
         """ONE host submission advancing every active slot ``horizon``
         decode cycles (``backend.decode_multi``)."""
         slots = tuple(sorted(active))
-        with self.tracer.span("decode_cycle", track="scheduler",
-                              cycle=st.cycles, occupancy=len(slots),
-                              horizon=horizon, multi=True,
-                              overlapped=overlapped):
-            bstate, out = self.session.backend.decode_multi(
-                bstate, tokens, slots, horizon=horizon,
-                stop_table=stop_table)
+        bstate, out = self.session.backend.decode_multi(
+            bstate, tokens, slots, horizon=horizon, stop_table=stop_table)
         st.cycles += 1
         st.multi_cycles += 1
         st.occupancy_sum += len(slots)
@@ -1060,12 +1057,12 @@ class Scheduler:
         insert rule)."""
         backend = self.session.backend
         tr = self.tracer
-        t0 = time.perf_counter()
-        toks = np.asarray(out.tokens, np.int32)   # ONE readback per N steps
-        valid = np.asarray(out.valid, bool)
-        dt = time.perf_counter() - t0
-        tr.add("readback", t0, dt, cat="phase", track="scheduler",
-               args={"overlapped": overlapped, "multi": True})
+        with tr.span("readback", track="scheduler", overlapped=overlapped,
+                     multi=True):
+            t0 = time.perf_counter()
+            toks = np.asarray(out.tokens, np.int32)   # ONE readback per N
+            valid = np.asarray(out.valid, bool)
+            dt = time.perf_counter() - t0
         if overlapped:
             st.overlap_readback_s += dt
         else:
@@ -1109,11 +1106,13 @@ class Scheduler:
                and self._async_safe(active)
                and all(len(active[s].tokens) + 2 * horizon
                        <= active[s].req.max_new_tokens for s in slots)):
-            bstate, _, out_next = self._issue_multi(
-                bstate, active, st, out.tokens[:, -1:], horizon, None,
-                overlapped=True)
-            bstate = self._retire_multi(out, slots, active, results,
-                                        bstate, st, overlapped=True)
+            with self._cycle_span(st, len(slots), horizon=horizon,
+                                  multi=True, overlapped=True):
+                bstate, _, out_next = self._issue_multi(
+                    bstate, active, st, out.tokens[:, -1:], horizon, None,
+                    overlapped=True)
+                bstate = self._retire_multi(out, slots, active, results,
+                                            bstate, st, overlapped=True)
             out = out_next
         return self._retire_multi(out, slots, active, results, bstate, st,
                                   overlapped=False)
@@ -1149,14 +1148,17 @@ class Scheduler:
                 continue
             horizon = self._multi_horizon(active)
             if horizon > 1 and self._multi_ok(active):
-                bstate, slots, out = self._issue_multi(
-                    bstate, active, st, self._host_tokens(active), horizon,
-                    self._stop_table(active))
+                with self._cycle_span(st, len(active), horizon=horizon,
+                                      multi=True):
+                    bstate, slots, out = self._issue_multi(
+                        bstate, active, st, self._host_tokens(active),
+                        horizon, self._stop_table(active))
                 bstate = self._drain_multi(bstate, out, slots, active,
                                            results, st, horizon)
                 continue
-            bstate, slots, out = self._issue_cycle(
-                bstate, active, st, self._host_tokens(active))
+            with self._cycle_span(st, len(active)):
+                bstate, slots, out = self._issue_cycle(
+                    bstate, active, st, self._host_tokens(active))
             bstate = self._drain_async(bstate, out, slots, active, results,
                                        st)
         self._bstate = bstate
@@ -1234,12 +1236,10 @@ class Scheduler:
         st.verify_dispatches += 1
         st.occupancy_sum += len(slots)
         self._track_kv(bstate, st)
-        t0 = time.perf_counter()
-        nxt = np.asarray(out.next_token, np.int32)       # (S, width)
-        dt = time.perf_counter() - t0
-        st.sync_readback_s += dt
-        tr.add("readback", t0, dt, cat="phase", track="scheduler",
-               args={"overlapped": False})
+        with tr.span("readback", track="scheduler", overlapped=False):
+            t0 = time.perf_counter()
+            nxt = np.asarray(out.next_token, np.int32)   # (S, width)
+            st.sync_readback_s += time.perf_counter() - t0
         for s in slots:
             a = active[s]
             d = drafts.get(s)
@@ -1524,25 +1524,29 @@ class Scheduler:
                              or (self._queue
                                  and (len(active) < self.num_slots
                                       or self.preemption != "off")))):
-                bstate, slots, out = self._issue_multi(
-                    bstate, active, st, self._host_tokens(active), horizon,
-                    self._stop_table(active))
+                with self._cycle_span(st, len(active), horizon=horizon,
+                                      multi=True):
+                    bstate, slots, out = self._issue_multi(
+                        bstate, active, st, self._host_tokens(active),
+                        horizon, self._stop_table(active))
                 bstate = self._drain_multi(bstate, out, slots, active,
                                            results, st, horizon)
                 continue
-            bstate, slots, out = self._issue_cycle(
-                bstate, active, st, self._host_tokens(active))
             # stay synchronous while prompts are mid-prefill (their next
             # chunk must not wait behind a deferred readback), while
             # scheduled arrivals or preempted requests are outstanding
             # (the drain loop would defer their admission/preemption
             # checks), or while a waiter could preempt a running slot
-            if (prefilling or self._future or self._preempted
-                    or (self._queue and (len(active) < self.num_slots
-                                         or self.preemption != "off"))):
-                bstate = self._retire_cycle(out, slots, active, results,
-                                            bstate, st, overlapped=False)
-            else:
+            sync = bool(prefilling or self._future or self._preempted
+                        or (self._queue and (len(active) < self.num_slots
+                                             or self.preemption != "off")))
+            with self._cycle_span(st, len(active)):
+                bstate, slots, out = self._issue_cycle(
+                    bstate, active, st, self._host_tokens(active))
+                if sync:
+                    bstate = self._retire_cycle(out, slots, active, results,
+                                                bstate, st, overlapped=False)
+            if not sync:
                 bstate = self._drain_async(bstate, out, slots, active,
                                            results, st)
         st.cow_copies = pg.cow_copies - cow0

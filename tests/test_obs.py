@@ -76,19 +76,28 @@ def test_disabled_tracer_is_noop_singleton():
         pass
     tr.instant("i")
     tr.counter("c", 1.0)
-    tr.add("r", 0.0, 1.0)
+    assert tr.open_span("main") is None
     assert len(tr) == 0
     assert len(NULL_TRACER) == 0 and not NULL_TRACER.enabled
 
 
 def test_dispatch_total_sums_dispatch_lane_args():
     tr = Tracer()
-    tr.add("dispatch:decode", 0.0, 1e-3, cat="dispatch",
-           args={"dispatches": 3})
-    tr.add("dispatch:prefill", 1.0, 1e-3, cat="dispatch",
-           args={"dispatches": 2})
-    tr.add("phase", 2.0, 1e-3, cat="phase", args={"dispatches": 99})
+    with tr.span("dispatch:decode", cat="dispatch", op="decode") as sp:
+        with tr.span("enqueue"):
+            # counts land on the innermost open span of the asked category
+            assert tr.open_span("main", cat="dispatch") is sp
+            assert tr.open_span("main").name == "enqueue"
+        sp.tally(dispatches=1)
+        sp.tally(dispatches=2)
+    with tr.span("dispatch:prefill", cat="dispatch"):
+        tr.open_span("main", cat="dispatch").tally(dispatches=2)
+    with tr.span("phase", dispatches=99):
+        pass
+    assert tr.open_span("main") is None
     assert tr.dispatch_total() == 5
+    ev = {e.name: e for e in tr.events()}
+    assert ev["dispatch:decode"].args == {"op": "decode", "dispatches": 3}
 
 
 # ---------------------------------------------------------------------------
@@ -101,8 +110,9 @@ def test_perfetto_export_schema():
         pass
     tr.instant("hit", track="paging")
     tr.counter("occupancy", 2.0, track="scheduler")
-    tr.add("dispatch:decode", tr.events()[0].ts, 1e-4, cat="dispatch",
-           track="backend:model", args={"dispatches": 1})
+    with tr.span("dispatch:decode", cat="dispatch", track="backend:model",
+                 dispatches=1):
+        pass
     doc = to_trace_events(tr)
     validate_trace(doc)                    # raises on violation
     evs = doc["traceEvents"]
@@ -348,10 +358,103 @@ def test_disabled_tracer_overhead_under_budget(setup):
         with tr.span("decode_cycle", track="scheduler", cycle=0):
             pass
         tr.instant("x")
-        tr.add("d", 0.0, 0.0)
+        with tr.span("enqueue", track="backend:model"):
+            pass
     per_iter = (time.perf_counter() - t0) / n
     # ~8 tracer touch points per scheduler cycle; must stay under 2%
     overhead_frac = 8 * per_iter / cycle_s
     assert overhead_frac < 0.02, (
         f"disabled tracer costs {100 * overhead_frac:.3f}% of a "
         f"{1e3 * cycle_s:.2f} ms decode cycle")
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock; named scopes of the paged step
+# ---------------------------------------------------------------------------
+
+def _xplane_spans(path):
+    """(name, start_ns, end_ns) of every ``repro.*`` host event."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.end_ns) for e in line.events
+                    if e.name.startswith("repro.")]
+    return out
+
+
+def test_live_spans_reach_the_profiler(setup, tmp_path):
+    """Under ``jax.profiler.trace`` every live span is also a profiler
+    event: a decode cycle holds its dispatch, which holds the jitted
+    call, and the trace counts exactly the cycles the scheduler ran."""
+    cfg, model, params = setup
+    backend = create_backend("model", model, params, batch=1, max_len=64)
+    tr = Tracer()
+    sched = Scheduler(InferenceSession(backend), num_slots=1,
+                      kv_layout="paged", prefill_chunk=8, block_size=8,
+                      tracer=tr)
+    prompts = _prompts(cfg, 2, seed=11)
+    sched.submit(ServeRequest(prompt=prompts[0], max_new_tokens=4))
+    sched.run()                            # compile outside the trace
+    tr.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        sched.submit(ServeRequest(prompt=prompts[1], max_new_tokens=6))
+        sched.run()
+    st = sched.last_stats
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = _xplane_spans(path)
+    cycles = [s for s in spans if s[0] == "repro.decode_cycle"]
+    assert len(cycles) == tr.count("decode_cycle") == st.cycles > 0
+
+    def inside(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+    dispatches = [s for s in spans if s[0] == "repro.dispatch:decode_batch"]
+    enqueues = [s for s in spans if s[0] == "repro.enqueue"]
+    assert len(dispatches) == st.cycles
+    for d in dispatches:
+        assert any(inside(d, c) for c in cycles)
+        assert any(inside(e, d) for e in enqueues)
+    names = {s[0] for s in spans}
+    assert {"repro.prepare", "repro.upload", "repro.adopt",
+            "repro.readback", "repro.sample_emit", "repro.admit",
+            "repro.prefill_chunk",
+            "repro.dispatch:prefill_chunk"} <= names
+    # the ring buffer still holds the same spans for the Perfetto export
+    assert tr.dispatch_total() == st.dispatches
+    validate_trace(to_trace_events(tr))
+
+
+def test_null_tracer_constructs_no_trace_annotation(monkeypatch):
+    import repro.obs.tracer as tracer_mod
+    made = []
+
+    class Counting(tracer_mod.TraceAnnotation):
+        def __init__(self, name, **kw):
+            made.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Counting)
+    for _ in range(10):
+        with NULL_TRACER.span("decode_cycle", track="scheduler", cycle=0):
+            pass
+    assert made == []
+    tr = Tracer()
+    with tr.span("decode_cycle", track="scheduler"):
+        with tr.span("dispatch:decode_batch", cat="dispatch"):
+            pass
+    assert made == ["repro.decode_cycle", "repro.dispatch:decode_batch"]
+
+
+def test_record_outside_a_dispatch_span_is_refused(setup):
+    """A live tracer makes a dispatch recorded outside any dispatch span
+    an error instead of a silently lost count."""
+    from repro.core.engine import RunStats
+    cfg, model, params = setup
+    backend = create_backend("model", model, params, batch=1, max_len=64)
+    backend.tracer = Tracer()
+    rs = RunStats(wall_s=0.0, dispatches=1, shape_ops=0, sync_mode="none")
+    with pytest.raises(RuntimeError, match="outside a dispatch span"):
+        backend._record(rs, op="decode")
+    with backend._dispatch_span("decode"):
+        backend._record(rs, op="decode")
+    assert backend.tracer.dispatch_total() == 1
