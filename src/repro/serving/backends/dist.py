@@ -49,6 +49,7 @@ from repro.models.transformer import CHUNKED_ATTENTION_MIN_SEQ
 from repro.serving.backends.base import (BackendCapabilities, BatchState,
                                          ExecutionBackend, State, StepOutput,
                                          device_snapshot, register_backend)
+from repro.serving.paging.attention import gather_blocks, write_kv
 
 
 def _auto_stages(num_layers: int, n_devices: int) -> int:
@@ -226,24 +227,16 @@ class DistBackend(ExecutionBackend):
         return cache, logits, jnp.argmax(logits, -1).astype(jnp.int32)
 
     # -- paged KV: per-stage layer-slice arenas under shard_map ----------
-    @staticmethod
-    def _gather_local(arena_local, table):
-        """(N, Lc, Bs, KV, hd) stage-local arena + (S, W) block table →
-        (Lc, S, W·Bs, KV, hd) dense per-layer view of this stage's slice,
-        position-identical to the dense cache layout."""
-        g = arena_local[table]                  # (S, W, Lc, Bs, KV, hd)
-        s, w, lc, bs = g.shape[:4]
-        g = jnp.moveaxis(g, 2, 0)               # (Lc, S, W, Bs, KV, hd)
-        return g.reshape(lc, s, w * bs, *g.shape[4:])
-
     def _sharded_decode_paged(self, params, ak, av, table, pos, tokens):
         """One paged decode cycle for EVERY active slot, pipelined.
 
         Each stage gathers its layer-slice of the arena through the
         (replicated) block table, runs its layer chunk at per-row
-        positions, and the new K/V rows are scattered back into the
-        stage-sharded arena — the per-row scatter is what lets per-slot
-        positions batch where the dense pipeline could not.
+        positions, and scatters its layers' new K/V rows back into its own
+        slice — the per-row scatter is what lets per-slot positions batch
+        where the dense pipeline could not.  Reads and writes go through
+        the paged attention's own ``gather_blocks``/``write_kv``, so the
+        stage slices keep the arena layout ``arena_shape`` chose.
         """
         cfg = self.cfg
         x = params["embed"][tokens]             # (S_slots, 1, d)
@@ -252,8 +245,8 @@ class DistBackend(ExecutionBackend):
         hd = cfg.resolved_head_dim
 
         def inner(blocks_local, xx, ak_l, av_l, tbl, ps):
-            kd = self._gather_local(ak_l, tbl)
-            vd = self._gather_local(av_l, tbl)
+            kd = gather_blocks(ak_l, tbl, cfg.num_kv_heads)
+            vd = gather_blocks(av_l, tbl, cfg.num_kv_heads)
 
             def block_step(bl, xc, carry):
                 def one(c, scan_in):
@@ -265,7 +258,10 @@ class DistBackend(ExecutionBackend):
             body = self._pipeline_blocks(block_step)
             init = (jnp.zeros((lc, nslots, cfg.num_kv_heads, hd), xx.dtype),
                     jnp.zeros((lc, nslots, cfg.num_kv_heads, hd), xx.dtype))
-            return body(blocks_local, xx, init)
+            xx, (knew, vnew) = body(blocks_local, xx, init)
+            # the write lands in each slot's current block (host made it
+            # exclusively ours)
+            return xx, write_kv(ak_l, av_l, tbl, ps, knew, vnew)
 
         def run(blocks, x, ak, av, table, pos):
             fn = jax.shard_map(inner, mesh=self.mesh,
@@ -273,23 +269,13 @@ class DistBackend(ExecutionBackend):
                                                       blocks), P(),
                                          P(None, "stage"), P(None, "stage"),
                                          P(), P()),
-                               out_specs=(P(), (P("stage"), P("stage"))),
+                               out_specs=(P(), (P(None, "stage"),
+                                                P(None, "stage"))),
                                check_vma=False)
             return fn(blocks, x, ak, av, table, pos)
 
-        x, (knew, vnew) = run(params["blocks"], x, ak, av, table, pos)
+        x, (ak, av) = run(params["blocks"], x, ak, av, table, pos)
         logits = transformer.unembed(params, cfg, x)
-        bs = ak.shape[2]
-        rows = jnp.arange(nslots)
-        bids = table[rows, pos // bs]
-        offs = pos % bs
-        # knew (L, S_slots, KV, hd) → (S_slots, L, KV, hd); the write lands
-        # in each slot's current block (host made it exclusively ours), and
-        # the layer axis stays stage-local under the arena's sharding
-        ak = ak.at[bids, :, offs].set(jnp.moveaxis(knew, 0, 1)
-                                      .astype(ak.dtype))
-        av = av.at[bids, :, offs].set(jnp.moveaxis(vnew, 0, 1)
-                                      .astype(av.dtype))
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         return ak, av, logits, nxt
 
@@ -303,8 +289,8 @@ class DistBackend(ExecutionBackend):
         hd = cfg.resolved_head_dim
 
         def inner(blocks_local, xx, ak_l, av_l, tbl, p0):
-            kd = self._gather_local(ak_l, tbl)
-            vd = self._gather_local(av_l, tbl)
+            kd = gather_blocks(ak_l, tbl, cfg.num_kv_heads)
+            vd = gather_blocks(av_l, tbl, cfg.num_kv_heads)
             positions = p0 + jnp.arange(c)
 
             def block_step(bl, xc, carry):
@@ -317,7 +303,11 @@ class DistBackend(ExecutionBackend):
             body = self._pipeline_blocks(block_step)
             init = (jnp.zeros((lc, 1, c, cfg.num_kv_heads, hd), xx.dtype),
                     jnp.zeros((lc, 1, c, cfg.num_kv_heads, hd), xx.dtype))
-            return body(blocks_local, xx, init)
+            xx, (kch, vch) = body(blocks_local, xx, init)
+            # padded positions land in writable blocks and are overwritten
+            # before anything attends them
+            return xx, write_kv(ak_l, av_l, tbl, positions, kch[:, 0],
+                                vch[:, 0])
 
         def run(blocks, x, ak, av, table_row, pos0):
             fn = jax.shard_map(inner, mesh=self.mesh,
@@ -325,23 +315,14 @@ class DistBackend(ExecutionBackend):
                                                       blocks), P(),
                                          P(None, "stage"), P(None, "stage"),
                                          P(), P()),
-                               out_specs=(P(), (P("stage"), P("stage"))),
+                               out_specs=(P(), (P(None, "stage"),
+                                                P(None, "stage"))),
                                check_vma=False)
             return fn(blocks, x, ak, av, table_row, pos0)
 
-        x, (kch, vch) = run(params["blocks"], x, ak, av, table_row, pos0)
+        x, (ak, av) = run(params["blocks"], x, ak, av, table_row, pos0)
         x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
         logits = transformer.unembed(params, cfg, x_last)
-        bs = ak.shape[2]
-        idx = pos0 + jnp.arange(c)
-        bids = table_row[0, idx // bs]
-        offs = idx % bs
-        # kch (L, 1, C, KV, hd) → (C, L, KV, hd); padded positions land in
-        # writable blocks and are overwritten before anything attends them
-        ak = ak.at[bids, :, offs].set(jnp.moveaxis(kch[:, 0], 0, 1)
-                                      .astype(ak.dtype))
-        av = av.at[bids, :, offs].set(jnp.moveaxis(vch[:, 0], 0, 1)
-                                      .astype(av.dtype))
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         return ak, av, logits, nxt
 

@@ -7,6 +7,11 @@ carves one preallocated arena into fixed-size **blocks**:
 
     arena_k / arena_v : (num_blocks, layers, block_size, kv_heads, head_dim)
 
+or, where ``head_dim`` is not a multiple of the TPU's 128 lanes, the same
+with the heads merged into one lane-dense minor axis,
+``(num_blocks, layers, block_size, kv_heads * head_dim)``
+(``arena_shape``).
+
 ``BlockPool`` owns the arena plus the host-side free list and per-block
 reference counts; blocks are shared read-only between requests (and the
 ``RadixPrefixCache``) and copy-on-write forked the moment a writer touches
@@ -36,8 +41,29 @@ from repro.configs.base import ModelConfig
 from repro.serving.statecache.base import StateCache
 
 
+LANES = 128         # a TPU vector register's lane width
+
+
 def _ceildiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def arena_shape(cfg: ModelConfig, num_blocks: int, block_size: int
+                ) -> Tuple[int, ...]:
+    """The stacked arena's shape (K and V alike), block axis first.
+
+    ``(N, L, Bs, KV, hd)`` when ``head_dim`` fills whole 128-lane tiles;
+    otherwise ``(N, L, Bs, KV·hd)``.  A (KV, hd) = (2, 64) minor pair is
+    tiled half-empty into 128 lanes, and the TPU compiler then relays the
+    whole arena out before every gather; merged, the minor axis is lane
+    dense and the arena is read as stored.  Readers split the heads after
+    the gather (``attention.gather_blocks``) and writers merge them before
+    the scatter (``attention.write_kv``), so both shapes share one path.
+    """
+    hd = cfg.resolved_head_dim
+    heads = ((cfg.num_kv_heads, hd) if hd % LANES == 0
+             else (cfg.num_kv_heads * hd,))
+    return (num_blocks, cfg.num_layers, block_size) + heads
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -81,8 +107,10 @@ class BlockPool:
     list and refcounts are layout-blind):
 
     * ``stacked`` — ``arena_k``/``arena_v`` with the layer axis inside,
-      ``(num_blocks, L, block_size, KV, hd)``; what the jitted model-path
-      attention (``decode_step_paged``/``extend_step_paged``) consumes.
+      ``arena_shape(...)``: ``(num_blocks, L, block_size, KV, hd)``,
+      heads merged to ``KV·hd`` where ``hd`` is not lane-aligned; what
+      the jitted model-path attention
+      (``decode_step_paged``/``extend_step_paged``) consumes.
     * ``graph``   — ``tree`` of one ``k_arena_i``/``v_arena_i`` leaf per
       layer, ``(num_blocks, block_size, KV, hd)`` each, exactly the named
       inputs the paged decode/extend OpGraphs declare — handed to the
@@ -99,6 +127,7 @@ class BlockPool:
         dt = jnp.dtype(cfg.dtype)
         self.layout = layout
         self.num_layers = cfg.num_layers
+        self.kv_heads = cfg.num_kv_heads
         if layout == "graph":
             shape = (num_blocks, block_size, cfg.num_kv_heads, hd)
             self.tree = {}
@@ -106,8 +135,7 @@ class BlockPool:
                 self.tree[f"k_arena_{i}"] = jnp.zeros(shape, dt)
                 self.tree[f"v_arena_{i}"] = jnp.zeros(shape, dt)
         else:
-            shape = (num_blocks, cfg.num_layers, block_size,
-                     cfg.num_kv_heads, hd)
+            shape = arena_shape(cfg, num_blocks, block_size)
             self.arena_k = jnp.zeros(shape, dt)
             self.arena_v = jnp.zeros(shape, dt)
         self.num_blocks = num_blocks
@@ -299,6 +327,20 @@ class PagedKVCache(StateCache):
         self.cow_copies = 0
         from repro.obs.tracer import NULL_TRACER
         self.tracer = NULL_TRACER               # set by the scheduler
+
+    def attach_tracer(self, tracer) -> None:
+        """Record paging events on ``tracer`` from now on.  The first
+        attach of a live tracer marks which arena layout runs, once, as an
+        ``arena_layout`` instant on the ``paging`` track."""
+        if tracer is self.tracer:
+            return
+        self.tracer = tracer
+        if tracer.enabled:
+            pool = self.pool
+            arena = (pool.arena_k if pool.layout == "stacked"
+                     else pool.tree["k_arena_0"])
+            tracer.instant("arena_layout", track="paging",
+                           layout=pool.layout, shape=list(arena.shape))
 
     # -- StateCache hooks ------------------------------------------------
     def _on_allocate(self, slot: int) -> None:
@@ -553,11 +595,12 @@ class PagedKVCache(StateCache):
             v = np.stack([layer("v", i)
                           for i in range(self.pool.num_layers)])
             return {"k": k, "v": v}
-        ak = np.asarray(self.pool.arena_k)
-        av = np.asarray(self.pool.arena_v)
-        k = np.concatenate([ak[b] for b in ids], axis=1)[:, :n]
-        v = np.concatenate([av[b] for b in ids], axis=1)[:, :n]
-        return {"k": k, "v": v}
+
+        def heads(arena):
+            a = np.asarray(arena)
+            x = np.concatenate([a[b] for b in ids], axis=1)[:, :n]
+            return x.reshape(x.shape[:2] + (self.pool.kv_heads, -1))
+        return {"k": heads(self.pool.arena_k), "v": heads(self.pool.arena_v)}
 
     # -- memory accounting ----------------------------------------------
     @property

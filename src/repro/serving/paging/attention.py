@@ -39,14 +39,16 @@ from repro.configs.base import ModelConfig
 from repro.models import transformer
 
 
-def gather_blocks(arena: jax.Array, table: jax.Array) -> jax.Array:
-    """(N, L, Bs, KV, hd) arena + (S, W) block table → (L, S, W·Bs, KV, hd)
-    dense per-layer view, position-compatible with the dense cache."""
+def gather_blocks(arena: jax.Array, table: jax.Array, kv_heads: int
+                  ) -> jax.Array:
+    """Arena as ``allocator.arena_shape`` stores it, (N, L, Bs, KV, hd) or
+    (N, L, Bs, KV·hd), + (S, W) block table → (L, S, W·Bs, KV, hd) dense
+    per-layer view, position-compatible with the dense cache."""
     with jax.named_scope("kv_gather"):
-        g = arena[table]                           # (S, W, L, Bs, KV, hd)
+        g = arena[table]                           # (S, W, L, Bs, ...)
         s, w, nl, bs = g.shape[:4]
-        g = jnp.moveaxis(g, 2, 0)                  # (L, S, W, Bs, KV, hd)
-        return g.reshape(nl, s, w * bs, *g.shape[4:])
+        g = jnp.moveaxis(g, 2, 0)                  # (L, S, W, Bs, ...)
+        return g.reshape(nl, s, w * bs, kv_heads, -1)
 
 
 def _unembed_argmax(params, cfg: ModelConfig, x: jax.Array
@@ -57,27 +59,29 @@ def _unembed_argmax(params, cfg: ModelConfig, x: jax.Array
         return logits, jnp.argmax(logits, -1).astype(jnp.int32)
 
 
-def _write_kv(arena_k: jax.Array, arena_v: jax.Array, table: jax.Array,
-              positions: jax.Array, knew: jax.Array, vnew: jax.Array
-              ) -> Tuple[jax.Array, jax.Array]:
+def write_kv(arena_k: jax.Array, arena_v: jax.Array, table: jax.Array,
+             positions: jax.Array, knew: jax.Array, vnew: jax.Array
+             ) -> Tuple[jax.Array, jax.Array]:
     """Scatter the new K/V into the arena blocks that ``table`` maps
     ``positions`` to, under the ``kv_write`` scope.
 
     ``table`` is indexed by ``(rows, positions // block_size)``;
     ``knew``/``vnew`` are (L, *positions.shape, KV, hd), layer first as
-    the layer scan stacks them.  The advanced indices (block, offset) are
-    separated by the layer slice, so they move to the front and the
-    layer axis goes after them."""
+    the layer scan stacks them.  (KV, hd) take the arena's own minor
+    shape (``allocator.arena_shape``), and each (block, layer, offset)
+    triple indexes one stored row, so the scatter writes whole rows and
+    needs no relayout of the arena around it."""
     with jax.named_scope("kv_write"):
-        bs = arena_k.shape[2]
+        nl, bs = arena_k.shape[1:3]
         rows = jnp.arange(table.shape[0]).reshape(
             (-1,) + (1,) * (positions.ndim - 1))
         bids = table[rows, positions // bs]
         offs = positions % bs
+        layers = jnp.arange(nl).reshape((nl,) + (1,) * positions.ndim)
 
         def put(arena, new):
-            new = jnp.moveaxis(new, 0, positions.ndim)
-            return arena.at[bids, :, offs].set(new.astype(arena.dtype))
+            new = new.reshape(*new.shape[:-2], *arena.shape[3:])
+            return arena.at[bids, layers, offs].set(new.astype(arena.dtype))
         return put(arena_k, knew), put(arena_v, vnew)
 
 
@@ -92,8 +96,8 @@ def decode_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     dispatch as the dense rows path; only the cache plumbing differs.
     """
     x = params["embed"][tokens]
-    kd = gather_blocks(arena_k, table)
-    vd = gather_blocks(arena_v, table)
+    kd = gather_blocks(arena_k, table, cfg.num_kv_heads)
+    vd = gather_blocks(arena_v, table, cfg.num_kv_heads)
 
     def body(carry, xs):
         p, kc, vc = xs
@@ -104,7 +108,7 @@ def decode_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     logits, nxt = _unembed_argmax(params, cfg, x)
     # each slot's new token lands in its current block, which
     # ensure_writable made exclusively ours
-    arena_k, arena_v = _write_kv(arena_k, arena_v, table, pos, knew, vnew)
+    arena_k, arena_v = write_kv(arena_k, arena_v, table, pos, knew, vnew)
     return arena_k, arena_v, logits, nxt
 
 
@@ -128,8 +132,8 @@ def verify_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     padding in ``extend_step_paged``).
     """
     x = params["embed"][tokens]
-    kd = gather_blocks(arena_k, table)
-    vd = gather_blocks(arena_v, table)
+    kd = gather_blocks(arena_k, table, cfg.num_kv_heads)
+    vd = gather_blocks(arena_v, table, cfg.num_kv_heads)
     c = tokens.shape[1]
     positions = pos[:, None] + jnp.arange(c)       # (S, C)
 
@@ -141,8 +145,8 @@ def verify_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     x, (kch, vch) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
     logits, nxt = _unembed_argmax(params, cfg, x)
     # the same scatter decode_step_paged makes, batched over the span axis
-    arena_k, arena_v = _write_kv(arena_k, arena_v, table, positions, kch,
-                                 vch)
+    arena_k, arena_v = write_kv(arena_k, arena_v, table, positions, kch,
+                                vch)
     return arena_k, arena_v, logits, nxt
 
 
@@ -161,8 +165,8 @@ def extend_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     dispatches for the shared prefix).
     """
     x = params["embed"][tokens]
-    kd = gather_blocks(arena_k, table_row)
-    vd = gather_blocks(arena_v, table_row)
+    kd = gather_blocks(arena_k, table_row, cfg.num_kv_heads)
+    vd = gather_blocks(arena_v, table_row, cfg.num_kv_heads)
     c = tokens.shape[1]
     positions = pos0 + jnp.arange(c)
 
@@ -176,6 +180,6 @@ def extend_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     logits, nxt = _unembed_argmax(params, cfg, x_last)
     # padded positions land in writable blocks and are overwritten before
     # anything can attend them
-    arena_k, arena_v = _write_kv(arena_k, arena_v, table_row, positions,
-                                 kch[:, 0], vch[:, 0])
+    arena_k, arena_v = write_kv(arena_k, arena_v, table_row, positions,
+                                kch[:, 0], vch[:, 0])
     return arena_k, arena_v, logits, nxt

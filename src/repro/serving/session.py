@@ -1430,7 +1430,7 @@ class Scheduler:
         pg = bstate["paged"]
         radix = bstate["radix"]
         if self.tracer.enabled:
-            pg.tracer = self.tracer
+            pg.attach_tracer(self.tracer)
             if radix is not None:
                 radix.tracer = self.tracer
         cow0 = pg.cow_copies

@@ -271,7 +271,8 @@ def test_graph_backend_dispatch_lane(setup):
 
 
 def test_paging_instants_recorded(setup):
-    """COW forks and radix hits surface as paging-track instants."""
+    """COW forks and radix hits surface as paging-track instants, after
+    one instant naming the arena layout that ran."""
     cfg, model, params = setup
     backend = create_backend("model", model, params, batch=1, max_len=64)
     tr = Tracer()
@@ -290,7 +291,12 @@ def test_paging_instants_recorded(setup):
     assert "radix_hit" in names
     assert "cow_fork" in names             # mid-block boundary fork
     assert all(e.track == "paging" for e in tr.events()
-               if e.name in ("radix_hit", "cow_fork"))
+               if e.name in ("radix_hit", "cow_fork", "arena_layout"))
+    layout = [e for e in tr.events() if e.name == "arena_layout"]
+    pool = sched._bstate["paged"].pool
+    assert len(layout) == 1                 # two runs, one attach
+    assert layout[0].args == {"layout": "stacked",
+                              "shape": list(pool.arena_k.shape)}
 
 
 # ---------------------------------------------------------------------------

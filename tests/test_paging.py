@@ -4,6 +4,7 @@ dense greedy parity, chunked-prefill parity, prefix-cache hits skipping the
 shared span, eviction under pressure, memory accounting, async readback,
 and the paged decode-graph variant."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +15,13 @@ from repro.configs import get_smoke_config
 from repro.configs.bench import BENCH_05B
 from repro.core.graphs import LEVELS, build_decode_graph
 from repro.core.opgraph import run_graph_pure
-from repro.models import build_model
+from repro.models import build_model, transformer
 from repro.serving import (BlockPool, InferenceSession, PagedKVCache,
                            RadixPrefixCache, Scheduler, ServeRequest,
                            SlotKVCache, create_backend)
+from repro.serving.backends.base import device_snapshot
+from repro.serving.paging import (arena_shape, decode_step_paged,
+                                  extend_step_paged, verify_step_paged)
 
 
 @pytest.fixture(scope="module")
@@ -653,3 +657,113 @@ def test_fork_churn_property(setup):
         _apply_fork_churn(model.cfg, ops)
 
     prop()
+
+
+# ---------------------------------------------------------------------------
+# arena layout rule: heads merged where head_dim is not lane-aligned
+# ---------------------------------------------------------------------------
+
+def _drive_paged(params, cfg, pg, seq, layout):
+    """Prefill ``seq[:, :13]`` in chunks of 8, decode three teacher-forced
+    tokens, then verify three more in one call, all through ``pg``'s block
+    tables; the arenas are reshaped to ``layout`` before the first step.
+    Tables and positions go up as snapshots, since the host mutates them
+    while a step may still run.  Returns the logits of every call, the
+    slot and its KV readout."""
+    pool = pg.pool
+    pool.set_arena(pool.arena_k.reshape(layout),
+                   pool.arena_v.reshape(layout))
+    extend = jax.jit(functools.partial(extend_step_paged, params, cfg))
+    decode = jax.jit(functools.partial(decode_step_paged, params, cfg))
+    verify = jax.jit(functools.partial(verify_step_paged, params, cfg))
+    slot = pg.allocate()
+    out = []
+    for pos0, valid in ((0, 8), (8, 5)):
+        pg.ensure_writable(slot, pos0, pos0 + 8)
+        chunk = np.zeros((1, 8), np.int32)
+        chunk[0, :valid] = seq[0, pos0:pos0 + valid]
+        ak, av, logits, _ = extend(pool.arena_k, pool.arena_v,
+                                   device_snapshot(pg.table[slot:slot + 1]),
+                                   jnp.int32(pos0), jnp.int32(valid),
+                                   jnp.asarray(chunk))
+        pool.set_arena(ak, av)
+        pg.pos[slot] = pos0 + valid
+        out.append(("extend", pos0 + valid - 1, logits[0, 0]))
+    for _ in range(3):
+        p = int(pg.pos[slot])
+        pg.ensure_writable(slot, p, p + 1)
+        toks = np.zeros((pg.num_slots, 1), np.int32)
+        toks[slot, 0] = seq[0, p]
+        ak, av, logits, _ = decode(pool.arena_k, pool.arena_v,
+                                   device_snapshot(pg.table),
+                                   device_snapshot(pg.pos), jnp.asarray(toks))
+        pool.set_arena(ak, av)
+        pg.advance([slot])
+        out.append(("decode", p, logits[slot, 0]))
+    p = int(pg.pos[slot])
+    pg.ensure_writable(slot, p, p + 3)
+    toks = np.zeros((pg.num_slots, 3), np.int32)
+    toks[slot] = seq[0, p:p + 3]
+    ak, av, logits, _ = verify(pool.arena_k, pool.arena_v,
+                               device_snapshot(pg.table),
+                               device_snapshot(pg.pos), jnp.asarray(toks))
+    pool.set_arena(ak, av)
+    out += [("verify", p + j, logits[slot, j]) for j in range(3)]
+    return out, slot, pg.gather(slot)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_arena_layout_follows_head_dim(head_dim):
+    """The stacked arena stores (KV, hd) merged exactly when hd is not a
+    multiple of 128 lanes, and nothing else changes: paged extend, decode
+    and verify match the dense forward's tokens and logits and are
+    bit-identical to the same steps on the split layout; the KV readout,
+    a COW fork and a swap round trip are exact."""
+    cfg = get_smoke_config("qwen2.5-0.5b", d_model=4 * head_dim, heads=4,
+                           kv_heads=2)
+    params = build_model(cfg).init_params(jax.random.PRNGKey(0))
+    L, KV, bs = cfg.num_layers, 2, 4
+    seq = np.random.default_rng(3).integers(
+        0, cfg.vocab_size, size=(1, 19)).astype(np.int32)
+    ref = np.asarray(transformer.forward(params, cfg, jnp.asarray(seq))[0][0])
+
+    def cache():
+        return PagedKVCache(cfg, 2, max_len=24, block_size=bs, table_slack=8)
+
+    pg = cache()
+    n = pg.pool.num_blocks
+    split = (n, L, bs, KV, head_dim)
+    stored = split if head_dim % 128 == 0 else (n, L, bs, KV * head_dim)
+    assert pg.pool.arena_k.shape == stored == pg.pool.arena_v.shape
+    assert arena_shape(cfg, n, bs) == stored
+
+    out, slot, kv = _drive_paged(params, cfg, pg, seq, stored)
+    out_split, _, kv_split = _drive_paged(params, cfg, cache(), seq, split)
+    for (kind, p, got), (_, _, want) in zip(out, out_split):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=f"{kind} at {p}")
+        assert int(np.argmax(got)) == int(np.argmax(ref[p])), (kind, p)
+        np.testing.assert_allclose(np.asarray(got), ref[p], rtol=1e-4,
+                                   atol=1e-4, err_msg=f"{kind} at {p}")
+    npos = int(pg.pos[slot])
+    assert kv["k"].shape == (L, npos, KV, head_dim) == kv["v"].shape
+    for c in ("k", "v"):
+        np.testing.assert_array_equal(kv[c], kv_split[c])
+
+    pool = pg.pool
+    bid = int(pg.table[slot, 0])
+    pool.incref(bid)                       # shared: the next write forks
+    nb, forked = pool.cow(bid)
+    assert forked
+    for a in (pool.arena_k, pool.arena_v):
+        np.testing.assert_array_equal(np.asarray(a[nb]), np.asarray(a[bid]))
+    pool.decref(nb)
+    pool.decref(bid)
+
+    chain = pg.swap_out(slot)
+    assert chain.host
+    slot, uploads = pg.swap_in(chain)
+    assert uploads == len(chain.host) and int(pg.pos[slot]) == npos
+    back = pg.gather(slot)
+    for c in ("k", "v"):
+        np.testing.assert_array_equal(back[c], kv[c])

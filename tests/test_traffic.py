@@ -3,6 +3,7 @@ and rate, workload synthesis invariants, swap-out/swap-in block-chain
 integrity (refcounts, radix nodes, byte-exact arena restore), and greedy
 parity across preempt→swap/recompute→resume cycles on the paged model
 backend — the oversubscription machinery ``bench_traffic.py`` rides on."""
+import dataclasses
 import time
 
 import jax
@@ -226,10 +227,16 @@ def test_preemption_parity_and_counters(setup, mode):
     sched = Scheduler(session, num_slots=2, kv_layout="paged",
                       prefill_chunk=8, block_size=8, preemption=mode)
     reqs = _traffic_reqs(prompts, tokens, hi_idx=3)
+
+    def arrive(i, _toks):
+        # the high-priority request lands while both slots decode
+        # low-priority work: t1's second token comes from a decode step,
+        # and t0 was admitted and prefilled before it
+        if i == 1:
+            sched.submit(reqs[3])
+    reqs[1] = dataclasses.replace(reqs[1], stream=arrive)
     for r in reqs[:3]:
         sched.submit(r)
-    # the high-priority request lands while both slots decode low-priority
-    sched.submit_at(reqs[3], time.perf_counter() + 0.05)
     results = sched.run()
     st = sched.last_stats
 
