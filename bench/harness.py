@@ -33,6 +33,7 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -40,7 +41,7 @@ import jax
 import numpy as np
 
 from bench import costs, trace_reduce, weights, workload
-from bench.reference import qwen2 as reference
+from bench.families import family, reference
 
 from repro.models import build_model
 from repro.serving import (InferenceSession, Scheduler, SchedulerConfig,
@@ -56,15 +57,9 @@ def report(phase: str, **kv) -> None:
 # -- the program, as the configuration states it ----------------------------
 
 def check_program_config(mcfg, cfg: Dict) -> None:
-    """The program's model config must be the configuration file's."""
-    n = weights.dims(cfg)
-    want = {"num_layers": n["L"], "d_model": n["d"], "num_heads": n["H"],
-            "num_kv_heads": n["KV"], "resolved_head_dim": n["hd"],
-            "d_ff": n["F"], "vocab_size": n["V"],
-            "rope_theta": float(cfg["rope_theta"]),
-            "rms_eps": float(cfg["rms_norm_eps"]),
-            "tie_embeddings": bool(cfg["tie_word_embeddings"]),
-            "qkv_bias": True, "qk_norm": False, "sliding_window": None}
+    """The program's model config must be the configuration file's, as
+    its model family states it."""
+    want = family(cfg).program_config(cfg)
     got = {k: getattr(mcfg, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
@@ -72,14 +67,11 @@ def check_program_config(mcfg, cfg: Dict) -> None:
                          f"configuration file (got, want): {bad}")
 
 
-def program_params(w: Dict, model, key) -> Dict:
-    """The benchmark's weights in the program's parameter tree, checked
-    leaf by leaf against the tree the program's own init would make."""
-    p = {"embed": w["embed"], "final_norm": w["final_norm"],
-         "blocks": {"attn_norm": w["attn_norm"], "ffn_norm": w["mlp_norm"],
-                    "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo",
-                                               "bq", "bk", "bv")},
-                    "ffn": {k: w[k] for k in ("w_gate", "w_up", "w_down")}}}
+def program_params(cfg: Dict, w: Dict, model, key) -> Dict:
+    """The benchmark's weights in the program's parameter tree (the model
+    family's ``program_params``), checked leaf by leaf against the tree
+    the program's own init would make."""
+    p = family(cfg).program_params(w)
     want = jax.eval_shape(model.init_params, key)
     if jax.tree.structure(want) != jax.tree.structure(p):
         raise SystemExit(f"program parameter tree differs: "
@@ -169,9 +161,11 @@ class Tracing:
 
     The device is drained (``sync``) just before the profiler starts and
     just before it stops, so no program in the traced window is cut off
-    at either end.  Stopping writes the trace out, which holds the host
-    for a while (``stall_s``): that is why the trace covers the end of the
-    window and not its start."""
+    at either end.  The trace lasts ``seconds`` from its start, also
+    where a host stall delayed the first poll after ``start_at``, and is
+    cut short only where the run ends.  Stopping writes the trace out,
+    which holds the host for a while (``stall_s``): that is why the trace
+    covers the end of the window and not its start."""
 
     def __init__(self, seconds: float, sync: Callable[[], None]) -> None:
         self.seconds = seconds
@@ -198,6 +192,7 @@ class Tracing:
             self._window = jax.profiler.TraceAnnotation(trace_reduce.WINDOW)
             self._window.__enter__()
             self.t_start = time.perf_counter()
+            self.stop_at = max(self.stop_at, self.t_start + self.seconds)
             self.stall_s["start"] = self.t_start - now
         elif (self.t_start is not None and self.t_stop is None
               and now >= self.stop_at):
@@ -265,6 +260,34 @@ class Tracing:
 
 
 # -- the window ---------------------------------------------------------------
+
+def device_arrays(root) -> List[jax.Array]:
+    """Every live device array reachable from ``root`` through dicts,
+    lists, tuples and object attributes."""
+    out, seen, todo = [], set(), [root]
+    while todo:
+        x = todo.pop()
+        if id(x) in seen:
+            continue
+        seen.add(id(x))
+        if isinstance(x, jax.Array):
+            if not x.is_deleted():
+                out.append(x)
+        elif isinstance(x, dict):
+            todo.extend(x.values())
+        elif isinstance(x, (list, tuple)):
+            todo.extend(x)
+        elif hasattr(x, "__dict__") and not isinstance(
+                x, (type, types.ModuleType, types.FunctionType)):
+            todo.extend(vars(x).values())
+    return out
+
+
+def drain(bstate) -> None:
+    """Wait for every device array of the scheduler's batch state, so
+    that no program that writes the cache is still running."""
+    jax.block_until_ready(device_arrays(bstate))
+
 
 def serve_request(q: workload.Req) -> ServeRequest:
     return ServeRequest(prompt=q.prompt.reshape(1, -1),
@@ -337,6 +360,7 @@ def serve(*, cfg: Dict, traffic: Dict, mcfg, seed: int, seconds: float,
     """Set up, warm up and serve one cell's window, read its metrics
     (``metrics``: the cell's entries for this mode, end-to-end without
     trace, per-layer with it), and free the program."""
+    t_serve = time.perf_counter()
     peak = None
     if require_tpu:
         require_chips(chips)
@@ -344,27 +368,29 @@ def serve(*, cfg: Dict, traffic: Dict, mcfg, seed: int, seconds: float,
     workload.check(traffic)
     check_program_config(mcfg, cfg)
     clock = CompileClock()
-    vocab = weights.dims(cfg)["V"]
+    vocab = int(cfg["vocab_size"])
 
     w = weights.make_weights(cfg, seed, dtype)
     model = build_model(mcfg)
-    params = program_params(w, model, jax.random.PRNGKey(0))
-    backend = create_backend("model", model, params, batch=1,
+    params = program_params(cfg, w, model, jax.random.PRNGKey(0))
+    backend = create_backend("model", model, params,
+                             batch=traffic["scheduler"]["num_slots"],
                              max_len=traffic["max_len"])
+    t_built = time.perf_counter()
     rec = Record()
     session = TimedSession(backend, rec, annotate=trace)
     sched = Scheduler(session, config=SchedulerConfig(
         continuous=True, async_readback=True, **traffic["scheduler"]))
     tracing = Tracing(float(traffic["trace_seconds"]),
-                      sync=lambda: jax.block_until_ready(
-                          sched._bstate["paged"].pool.arena_k)) \
-        if trace else None
+                      sync=lambda: drain(sched._bstate)) if trace else None
     for round_ in workload.warmup(traffic, vocab):
         for q in round_:
             sched.submit(serve_request(q))
         sched.run()
-    jax.block_until_ready(sched._bstate["paged"].pool.arena_k)
-    report("setup", **clock.lap(), params=weights.param_count(cfg))
+    drain(sched._bstate)
+    report("setup", **clock.lap(), params=family(cfg).param_count(cfg),
+           start_s=t_serve - t_process, build_s=t_built - t_serve,
+           warm_up_s=time.perf_counter() - t_built)
 
     if tracing is not None:
         tracing.wrap(backend, sched)
@@ -429,8 +455,9 @@ def judge(s: Served, *, cfg: Dict, traffic: Dict, seed: int, dtype: str,
     gap, rows = None, 0
     if picked:
         w = weights.make_weights(cfg, seed, dtype)
-        gaps = [reference.token_gaps(w, cfg, q.prompt, s.tokens[q.rid],
-                                     quant) for q in picked]
+        ref = reference(cfg)
+        gaps = [ref.token_gaps(w, cfg, q.prompt, s.tokens[q.rid],
+                              quant) for q in picked]
         gap = float(max(g.max() for g in gaps))
         rows = int(sum(len(g) for g in gaps))
     report("check", quant=quant, logit_gap=gap, limit=limit, tokens=rows,

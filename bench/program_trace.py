@@ -25,11 +25,11 @@ from __future__ import annotations
 
 import bisect
 import collections
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence
 
 from bench import costs, trace_reduce as tr
+from bench.families import family
 from bench.trace_reduce import Interval, Span
-from bench.weights import dims
 
 PREFIX = "repro."
 DECODE = "jit__decode_paged"
@@ -210,19 +210,11 @@ def kv_paging_ms(ctx: Dict) -> Optional[float]:
     return 1e3 * (s.get("kv_gather", 0.0) + s.get("kv_write", 0.0)) / n
 
 
-def mlp_step(cfg: Dict, rows: float, itemsize: int = 2
-             ) -> Tuple[float, int]:
-    """(FLOPs, bytes) of one decode step's MLP over ``rows`` rows: the
-    gate, up and down weights (3·d·F per layer) read once."""
-    n = dims(cfg)
-    w = 3 * n["d"] * n["F"] * n["L"]
-    return 2 * w * rows, w * itemsize
-
-
 def mlp_roofline(ctx: Dict) -> Optional[float]:
-    """Least time of the decode steps' MLP (``mlp_step``, the larger of
-    its FLOPs and its weight bytes over the chip's peaks) over the device
-    time scoped ``mlp`` per ``jit__decode_paged`` run, in percent."""
+    """Least time of the decode steps' MLP (the model family's
+    ``mlp_step``, the larger of its FLOPs and its weight bytes over the
+    chip's peaks) over the device time scoped ``mlp`` per
+    ``jit__decode_paged`` run, in percent."""
     p, n, rec = ctx.get("program_trace"), _decode_runs(ctx), \
         ctx.get("tracing")
     if not p or not n or ctx.get("peak") is None or rec is None \
@@ -232,5 +224,7 @@ def mlp_roofline(ctx: Dict) -> Optional[float]:
     if mlp_s <= 0:
         return None
     rows = sum(len(c) for c in rec.decode) / len(rec.decode)
-    least = costs.least_time(*mlp_step(ctx["cfg"], rows), ctx["peak"])[0]
+    cfg = ctx["cfg"]
+    least = costs.least_time(*family(cfg).mlp_step(cfg, rows),
+                             ctx["peak"])[0]
     return 100.0 * least / mlp_s

@@ -32,7 +32,7 @@ CFG = {"hidden_size": 256, "intermediate_size": 512,
        "num_attention_heads": 4, "num_hidden_layers": 4,
        "num_key_value_heads": 2, "rms_norm_eps": 1e-6,
        "rope_theta": 1_000_000.0, "tie_word_embeddings": True,
-       "vocab_size": 2048}
+       "vocab_size": 2048, "model_type": "qwen2"}
 MCFG = ModelConfig(name="small", family="dense", num_layers=4, d_model=256,
                    num_heads=4, num_kv_heads=2, d_ff=512, vocab_size=2048,
                    head_dim=64, qkv_bias=True, rope_theta=1_000_000.0,

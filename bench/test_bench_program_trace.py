@@ -23,6 +23,7 @@ import pytest
 from bench import costs
 from bench import program_trace as pt
 from bench import trace_reduce as tr
+from bench.families import family
 from bench.trace_reduce import Span
 
 from repro.obs import SpanEvent
@@ -109,7 +110,8 @@ def test_cycle_host_time_leaves_out_dispatch_and_readback():
 
 CFG = {"hidden_size": 256, "intermediate_size": 512,
        "num_attention_heads": 4, "num_hidden_layers": 4,
-       "num_key_value_heads": 2, "vocab_size": 2048}
+       "num_key_value_heads": 2, "vocab_size": 2048,
+       "model_type": "qwen2"}
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
 
 
@@ -124,7 +126,7 @@ def test_paging_time_and_mlp_roofline_per_decode_run():
     assert pt.kv_paging_ms(ctx) == pytest.approx(1.5)
     assert pt.dispatch_idle_us(ctx) == pytest.approx(200.0)
     weights = 3 * 256 * 512 * 4
-    assert pt.mlp_step(CFG, 1) == (2 * weights, 2 * weights)
+    assert family(CFG).mlp_step(CFG, 1) == (2 * weights, 2 * weights)
     # bytes bound: 2 * weights at 1e9 B/s over 2.5 ms of MLP per run
     assert pt.mlp_roofline(ctx) == pytest.approx(
         100 * (2 * weights / 1e9) / 0.0025)

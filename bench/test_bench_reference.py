@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import harness, weights
+from bench.families import family
 from bench.reference import qwen2 as reference
 
 from repro.configs.base import ModelConfig
@@ -17,7 +18,7 @@ CFG = {"hidden_size": 64, "intermediate_size": 128,
        "num_attention_heads": 4, "num_hidden_layers": 3,
        "num_key_value_heads": 2, "rms_norm_eps": 1e-6,
        "rope_theta": 1_000_000.0, "tie_word_embeddings": True,
-       "vocab_size": 512}
+       "vocab_size": 512, "model_type": "qwen2"}
 
 
 def program_cfg(dtype):
@@ -34,7 +35,7 @@ def setup():
     harness.check_program_config(mcfg, CFG)
     w = weights.make_weights(CFG, 2**31 + 5, "float32")
     model = build_model(mcfg)
-    params = harness.program_params(w, model, jax.random.PRNGKey(0))
+    params = harness.program_params(CFG, w, model, jax.random.PRNGKey(0))
     toks = np.random.default_rng(0).integers(0, 512, 40).astype(np.int32)
     return mcfg, w, params, toks
 
@@ -83,5 +84,5 @@ def test_weights_depend_on_the_seed_only():
     assert not (np.asarray(a["wq"], np.float32)
                 == np.asarray(c["wq"], np.float32)).all()
     assert a["embed"].dtype == jnp.bfloat16
-    assert weights.param_count(CFG) == sum(int(np.prod(v.shape))
-                                           for v in a.values())
+    assert family(CFG).param_count(CFG) == sum(int(np.prod(v.shape))
+                                               for v in a.values())

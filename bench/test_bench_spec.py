@@ -74,6 +74,9 @@ def test_every_name_has_its_file():
     for c in configs.values():
         body = json.loads((ROOT / c["file"]).read_text())
         assert body["name"] == c["name"] and body["reduced"] == c["reduced"]
+        t = body["config"]["model_type"]
+        assert (ROOT / "bench" / "families" / f"{t}.py").is_file()
+        assert (ROOT / "bench" / "reference" / f"{t}.py").is_file()
     for cell in SPEC["workloads"]:
         assert cell["config"] in configs
         workload.check(json.loads((ROOT / "bench" / "traffic"

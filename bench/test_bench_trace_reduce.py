@@ -4,12 +4,15 @@ a trace recorded on a TPU v5e (``testdata/decode-b1.xplane.pb``: a short
 traced window of the ``qwen05b-decode-b1`` cell around one decode step,
 the device drained before the profiler started and stopped; recorded
 from a traced run of the harness with ``trace_seconds`` 0.012 of a 1 s
-window, seed 5, the profiler's output directory kept)."""
+window, seed 5, the profiler's output directory kept); and the
+harness's traced window, which lasts its length from its start."""
+import shutil
+import time
 from pathlib import Path
 
 import pytest
 
-from bench import trace_reduce as tr
+from bench import harness, trace_reduce as tr
 from bench.trace_reduce import Span
 
 TRACE = Path(__file__).resolve().parent / "testdata" / "decode-b1.xplane.pb"
@@ -130,3 +133,19 @@ def test_recorded_gaps_are_put_down_to_bench_annotations(recorded):
     assert labels and all(k.startswith("bench.") or k == tr.NONE
                           for k in labels)
     assert "bench.decode_batch" in labels
+
+
+def test_a_late_start_still_traces_its_full_length():
+    """A host stall past ``start_at`` delays the profiler's start: the
+    trace then lasts its length from there, and does not stop at the
+    next poll with a step or two in it."""
+    t = harness.Tracing(0.5, sync=lambda: None)
+    t.arm(time.perf_counter() - 10.0, 1.0)     # start_at and stop_at passed
+    try:
+        t.poll()
+        assert t.t_start is not None
+        t.poll()
+        assert t.t_stop is None and t.stop_at == t.t_start + 0.5
+    finally:
+        t.stop()
+        shutil.rmtree(t.dir, ignore_errors=True)
