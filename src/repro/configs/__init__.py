@@ -8,14 +8,14 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 from repro.configs.base import (EncoderConfig, ModelConfig, MoEConfig,
-                                RGLRUConfig, SSMConfig, reduced)
+                                RGLRUConfig, RopeConfig, SSMConfig, reduced)
 from repro.configs.shapes import (ALL_SHAPES, DECODE_32K, LONG_500K,
                                   PREFILL_32K, SHAPES, TRAIN_4K, ShapeSpec,
                                   shapes_for)
 
 from repro.configs import (granite_moe_1b_a400m, internvl2_1b, mamba2_13b,
-                           phi3_medium_14b, qwen15_110b, qwen2_15b,
-                           qwen25_05b, qwen25_15b, qwen3_14b,
+                           mellum2_12b_a25b, phi3_medium_14b, qwen15_110b,
+                           qwen2_15b, qwen25_05b, qwen25_15b, qwen3_14b,
                            qwen3_moe_235b_a22b, recurrentgemma_9b,
                            whisper_tiny)
 
@@ -39,7 +39,13 @@ PAPER_MODELS: Dict[str, ModelConfig] = {
     "qwen2.5-1.5b": qwen25_15b.CONFIG,
 }
 
-REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}
+# Models the chip benchmark serves beyond the paper's (``bench/``).
+BENCH_MODELS: Dict[str, ModelConfig] = {
+    "mellum2-12b-a2.5b": mellum2_12b_a25b.CONFIG,
+}
+
+REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS,
+                                    **BENCH_MODELS}
 
 
 def get_config(arch: str) -> ModelConfig:
@@ -62,8 +68,9 @@ def dryrun_cells() -> Tuple[Tuple[ModelConfig, ShapeSpec], ...]:
 
 
 __all__ = [
-    "ASSIGNED", "PAPER_MODELS", "REGISTRY", "ModelConfig", "MoEConfig",
-    "SSMConfig", "RGLRUConfig", "EncoderConfig", "ShapeSpec", "SHAPES",
+    "ASSIGNED", "PAPER_MODELS", "BENCH_MODELS", "REGISTRY", "ModelConfig",
+    "MoEConfig", "RopeConfig", "SSMConfig", "RGLRUConfig", "EncoderConfig",
+    "ShapeSpec", "SHAPES",
     "ALL_SHAPES", "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K",
     "get_config", "get_smoke_config", "dryrun_cells", "shapes_for", "reduced",
 ]

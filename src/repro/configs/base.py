@@ -8,7 +8,10 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
+
+#: the attention kinds a layer of ``ModelConfig.layer_types`` may be
+LAYER_KINDS = ("sliding", "full")
 
 
 @dataclass(frozen=True)
@@ -22,6 +25,20 @@ class MoEConfig:
     router_aux_weight: float = 0.01
     # number of shared (always-on) experts; 0 for the assigned archs
     num_shared_experts: int = 0
+
+
+class RopeConfig(NamedTuple):
+    """Rotary settings of one attention kind.  Plain RoPE sets only
+    ``theta``; YaRN (arXiv:2309.00071, Hugging Face ``rope_type: "yarn"``)
+    sets the other five too.  A tuple, so a configuration file's numbers
+    compare equal to it."""
+
+    theta: float
+    factor: Optional[float] = None            # YaRN: context scale
+    original_max_len: Optional[int] = None    # YaRN: trained context
+    beta_fast: Optional[float] = None         # YaRN: ramp ends, in
+    beta_slow: Optional[float] = None         # rotations over that context
+    attention_factor: Optional[float] = None  # YaRN: cos/sin scale
 
 
 @dataclass(frozen=True)
@@ -88,6 +105,12 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10000.0
     sliding_window: Optional[int] = None
+    # per-layer attention kinds ("sliding" | "full"); None: every layer
+    # alike, windowed by ``sliding_window`` if it is set
+    layer_types: Optional[Tuple[str, ...]] = None
+    # ((kind, RopeConfig), ...): the rotary settings of each kind in
+    # ``layer_types``; a kind left out rotates by ``rope_theta``
+    rope_by_kind: Tuple[Tuple[str, RopeConfig], ...] = ()
     # norm options
     rms_eps: float = 1e-6
     tie_embeddings: bool = False
@@ -109,6 +132,46 @@ class ModelConfig:
         if self.num_heads == 0:  # attention-free (SSM family)
             return 0
         return self.d_model // self.num_heads
+
+    @property
+    def experts_per_token(self) -> int:
+        """Routed experts per token (0 on a dense model)."""
+        return self.moe.top_k if self.moe is not None else 0
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "full"
+
+    def layer_window(self, i: int) -> Optional[int]:
+        """Layer ``i``'s sliding window, None where it attends to all."""
+        if self.layer_types is None:
+            return self.sliding_window
+        return self.sliding_window if self.layer_types[i] == "sliding" \
+            else None
+
+    def rope_for(self, kind: str) -> RopeConfig:
+        return dict(self.rope_by_kind).get(kind, RopeConfig(self.rope_theta))
+
+    @property
+    def uniform_layers(self) -> bool:
+        """True when every layer attends with the same window and rotary
+        settings, so one static program serves them all."""
+        n = self.num_layers
+        return (len({self.layer_window(i) for i in range(n)}) <= 1
+                and len({self.rope_for(self.layer_kind(i))
+                         for i in range(n)}) <= 1)
+
+    def __post_init__(self) -> None:
+        if self.layer_types is not None:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} "
+                                 f"layer_types for {self.num_layers} layers")
+            bad = set(self.layer_types) - set(LAYER_KINDS)
+            if bad:
+                raise ValueError(f"{self.name}: unknown layer kinds {bad}; "
+                                 f"known: {LAYER_KINDS}")
+            if "sliding" in self.layer_types and not self.sliding_window:
+                raise ValueError(f"{self.name}: sliding layers need a "
+                                 "sliding_window")
 
     @property
     def attn_free(self) -> bool:
@@ -181,7 +244,11 @@ class ModelConfig:
 def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
             heads: int = 4, kv_heads: Optional[int] = None, d_ff: int = 128,
             vocab: int = 256, experts: Optional[int] = None) -> ModelConfig:
-    """Shrink a config to a CPU-smoke-test size, preserving family structure."""
+    """Shrink a config to a CPU-smoke-test size, preserving family structure:
+    a per-layer pattern keeps at least one whole period, and routing stays
+    top-k of more experts than k."""
+    period = _period(cfg.layer_types) if cfg.layer_types else ()
+    layers = max(layers, len(period))
     kv = kv_heads if kv_heads is not None else max(1, min(cfg.num_kv_heads, heads // 2))
     kw = dict(
         num_layers=layers, d_model=d_model, num_heads=heads, num_kv_heads=kv,
@@ -205,4 +272,15 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 64,
                                       d_ff=d_ff, num_positions=16)
     if cfg.sliding_window is not None:
         kw["sliding_window"] = 32
+    if period:
+        kw["layer_types"] = tuple(period[i % len(period)]
+                                  for i in range(layers))
     return cfg.replace(**kw)
+
+
+def _period(kinds: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The shortest prefix whose repetition gives ``kinds``."""
+    for n in range(1, len(kinds) + 1):
+        if all(k == kinds[i % n] for i, k in enumerate(kinds)):
+            return kinds[:n]
+    return kinds

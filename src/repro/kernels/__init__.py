@@ -10,6 +10,8 @@ WGSL shaders to the TPU memory hierarchy (HBM→VMEM→MXU):
 * ``fused_softmax``    — one-pass row softmax (the paper's 84× §5.1 fix)
 * ``decode_attention`` — flash-style single-token GQA attention against a
                          long KV cache (the batch-1 decode hot loop)
+* ``routed_experts``   — a decode step's routed SwiGLU experts, each read
+                         once in place by scalar-prefetched id (MoE)
 
 Each kernel ships ``kernel.py`` (pallas_call + BlockSpec), ``ops.py``
 (jitted public entry point; interpret=True on CPU), ``ref.py`` (pure-jnp
@@ -21,6 +23,7 @@ from repro.kernels.fused_mlp.ops import fused_mlp
 from repro.kernels.fused_kv_proj.ops import fused_kv_proj
 from repro.kernels.fused_softmax.ops import fused_softmax
 from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.routed_experts.ops import routed_experts
 
 __all__ = ["tiled_matmul", "fused_rmsnorm", "fused_mlp", "fused_kv_proj",
-           "fused_softmax", "decode_attention"]
+           "fused_softmax", "decode_attention", "routed_experts"]

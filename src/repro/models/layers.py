@@ -13,6 +13,7 @@ function.  Conventions:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -66,19 +67,70 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_freqs(head_dim: int, rope) -> np.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071, as Hugging Face's
+    ``rope_type: "yarn"`` computes them), shape (head_dim//2,) float32.
+
+    ``rope`` is a ``configs.base.RopeConfig`` with every YaRN field set.
+    Dimensions that turn more than ``beta_fast`` times over the original
+    context keep their frequency (extrapolation), those that turn fewer
+    than ``beta_slow`` times are divided by ``factor`` (interpolation),
+    and a linear ramp between the two (ends floored and ceiled) mixes
+    them."""
+    base, dim = float(rope.theta), head_dim
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(rope.original_max_len
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extrapolate = 1 - ramp
+    inv = ((1.0 / (rope.factor * pos_freqs)) * (1 - extrapolate)
+           + (1.0 / pos_freqs) * extrapolate)
+    return inv.astype(np.float32)
+
+
+def rope_table(head_dim: int, rope) -> Tuple[np.ndarray, float]:
+    """(inverse frequencies (head_dim//2,) float32, cos/sin scale) of a
+    ``configs.base.RopeConfig``: plain RoPE at ``theta``, or YaRN where
+    ``factor`` is set."""
+    if rope.factor is None:
+        inv = 1.0 / (float(rope.theta) ** (
+            np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+        return inv.astype(np.float32), 1.0
+    scale = rope.attention_factor
+    if scale is None:
+        scale = 0.1 * math.log(rope.factor) + 1.0
+    return yarn_freqs(head_dim, rope), float(scale)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotate ``x (B, S, H, D)`` by position.  ``positions`` is (B, S) or (S,).
 
     Uses the half-rotation convention (x1,x2 split at D/2) like Llama/Qwen.
     """
-    b, s, h, d = x.shape
-    inv = rope_freqs(d, theta)  # (d/2,)
+    return apply_rope_freqs(x, positions, rope_freqs(x.shape[-1], theta))
+
+
+def apply_rope_freqs(x: jax.Array, positions: jax.Array, inv: jax.Array,
+                     scale=None) -> jax.Array:
+    """``apply_rope`` at inverse frequencies ``inv`` (D/2,), cos and sin
+    multiplied by ``scale`` where given (YaRN's attention factor)."""
     pos = positions.astype(jnp.float32)
     if pos.ndim == 1:
         pos = pos[None, :]
     ang = pos[:, :, None] * inv[None, None, :]          # (B, S, d/2)
     cos = jnp.cos(ang)[:, :, None, :]                   # (B, S, 1, d/2)
     sin = jnp.sin(ang)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -1,4 +1,7 @@
-"""Mixture-of-experts FFN with sort-based capacity dispatch.
+"""Mixture-of-experts FFN: sort-based capacity dispatch for training,
+and a dropless expert layer for serving.
+
+``moe_ffn`` (training) dispatches by capacity:
 
 TPU-friendly formulation: no ragged shapes, no (T, E, C) one-hot tensor.
 Tokens are grouped by expert with a stable argsort, truncated to a static
@@ -7,21 +10,40 @@ through a batched-einsum SwiGLU, and scatter-added back with their router
 weights.  Experts shard over the "model" mesh axis (expert parallelism);
 the dispatch gather/scatter lower to collectives GSPMD schedules.
 
-Covers qwen3-moe-235b-a22b (128e top-8) and granite-moe-1b-a400m (32e top-8).
+``moe_serve`` (every serving step: prefill, extend, decode, verify)
+drops no token.  It routes in float32 over every expert, then takes one
+of two paths by the step's static shape:
+
+* few rows (``rows · k`` at most the experts held, as in decode): the
+  routed-expert Pallas kernel (``kernels/routed_experts``) reads each
+  expert the rows picked once, in place, and nothing else;
+* many rows (a prefill chunk): the ``rows · k`` assignments sorted by
+  expert, grouped with ``jax.lax.ragged_dot`` and no capacity, so an
+  expert that many tokens pick computes them all.
+
+The layer is told which experts it holds (``held``): the router keeps
+its full width, and the layer returns what its own experts add, as one
+share of an expert-parallel layer does before the exchange.
+
+Covers qwen3-moe-235b-a22b (128e top-8), granite-moe-1b-a400m (32e top-8)
+and mellum2-12b-a2.5b (64e top-8).
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers as L
+from repro.kernels.routed_experts.ops import routed_experts
 from repro.sharding.activation import constrain_moe_block
 
 Params = Dict[str, Any]
+
+# an MoE FFN's expert weights, each (E, ...) a layer
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 CAPACITY_FACTOR = 1.25
 
@@ -131,3 +153,86 @@ def moe_ffn(p: Params, cfg: ModelConfig, x: jax.Array
     mean_p = jnp.mean(probs, axis=(0, 1))
     aux = n_e * jnp.sum(frac * mean_p) * e.router_aux_weight
     return y, aux
+
+
+# -- serving --------------------------------------------------------------
+
+def route(router: jax.Array, cfg: ModelConfig, x: jax.Array
+          ) -> Tuple[jax.Array, jax.Array]:
+    """x (T, d) → (weights (T, k) float32, expert ids (T, k) int32): float32
+    logits over every expert, softmax, top-k, renormalised to sum to 1."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, cfg.moe.top_k)
+        return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_i
+
+
+def _grouped(x: jax.Array, top_p: jax.Array, top_i: jax.Array,
+             w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+             lo: int, layer=None) -> jax.Array:
+    """Every (token, expert) assignment to a held expert, sorted by expert
+    and grouped with ``ragged_dot``: no capacity, so no token is dropped.
+    → (T, d) float32.
+
+    With ``layer`` the weights are stacks over layers, (L, E_held, ...),
+    of which this is layer ``layer``: the products run over the stacks
+    flattened to (L·E_held, ...), every group outside this layer's empty,
+    so the weights are read in place and no layer's slice is copied."""
+    t, k = top_i.shape
+    w = [w_gate, w_up, w_down]
+    if layer is None:
+        w, layer = [a[None] for a in w], 0
+    n_layers, n_held = w[0].shape[:2]
+    groups = n_layers * n_held
+    w = [a.reshape((groups,) + a.shape[2:]) for a in w]
+    local = (top_i - lo).reshape(-1)
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local + layer * n_held, groups)   # others last
+    order = jnp.argsort(key, stable=True)
+    rows = order // k
+    sizes = jnp.bincount(key, length=groups + 1)[:groups].astype(jnp.int32)
+    xs = x[rows]                                  # (T·k, d)
+    g = jax.lax.ragged_dot(xs, w[0], sizes,
+                           preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(xs, w[1], sizes,
+                           preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    y = jax.lax.ragged_dot(h, w[2], sizes,
+                           preferred_element_type=jnp.float32)
+    p = top_p.reshape(-1)[order]
+    y = jnp.where(held[order][:, None], y * p[:, None], 0.0)
+    return jnp.zeros((t, x.shape[-1]), jnp.float32).at[rows].add(y)
+
+
+def moe_serve(p: Params, cfg: ModelConfig, x: jax.Array, *,
+              held: Optional[Tuple[int, int]] = None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """The serving expert layer: x (..., d) → (y (..., d), expert ids
+    (..., k)).  No token is dropped.
+
+    ``held=(lo, hi)``: the layer holds experts ``[lo, hi)``, whose weights
+    are ``p["w_gate"]`` etc. (``hi - lo`` of them); ``p["router"]`` spans
+    every expert.  ``y`` is what the held experts add (all of the layer's
+    output where they are all held).  The ids are global.
+
+    Where ``p["layer"]`` is set (a paged step's layer scan,
+    ``transformer.split_experts``), the expert weights are the stacks of
+    every layer and this is layer ``p["layer"]``."""
+    e = cfg.moe
+    lo, hi = held if held is not None else (0, e.num_experts)
+    w = [p[n] for n in EXPERT_WEIGHTS]
+    if w[0].shape[-3] != hi - lo:
+        raise ValueError(f"held experts [{lo}, {hi}) but weights for "
+                         f"{w[0].shape[-3]}")
+    layer = p.get("layer")
+    shape = x.shape
+    xf = x.reshape(-1, shape[-1])
+    top_p, top_i = route(p["router"], cfg, xf)
+    with jax.named_scope("moe_experts"):
+        if xf.shape[0] * e.top_k <= hi - lo:
+            y = routed_experts(xf, top_i, top_p, *w, lo=lo, layer=layer)
+        else:
+            y = _grouped(xf, top_p, top_i, *w, lo, layer)
+        y = y.astype(x.dtype).reshape(shape)
+    return y, top_i.reshape(*shape[:-1], e.top_k)

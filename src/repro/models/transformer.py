@@ -8,6 +8,14 @@ Layer parameters are stacked along a leading layer axis and consumed with
 ``jax.lax.scan`` so the lowered HLO is O(1) in depth — essential for the
 94-layer MoE dry-run cells.  ``scan_layers=False`` unrolls (used by the
 dispatch-engine reproduction experiments, which need op-level granularity).
+
+Layers of different attention kinds (``ModelConfig.layer_types``: sliding
+and full, each with its own rotary settings) stay in the one scan: the
+scan carries each layer's window and RoPE table beside its weights
+(``attention_layers``).  A config whose layers are all alike carries
+nothing and keeps its static window and RoPE base, so its programs are
+what they were.  Serving steps run MoE layers through the dropless
+``moe.moe_serve``; training keeps ``moe.moe_ffn``.
 """
 from __future__ import annotations
 
@@ -27,6 +35,10 @@ Params = Dict[str, Any]
 
 # threshold above which prefill switches to the memory-bounded chunked path
 CHUNKED_ATTENTION_MIN_SEQ = 8192
+
+# the window of a layer that attends to every position, where windows
+# are scanned per layer
+NO_WINDOW = 1 << 30
 
 
 def _dtype(cfg: ModelConfig):
@@ -97,6 +109,72 @@ def init_params(rng, cfg: ModelConfig) -> Params:
 
 
 # ---------------------------------------------------------------------------
+# per-layer attention settings
+# ---------------------------------------------------------------------------
+
+def attention_layers(cfg: ModelConfig) -> Optional[Params]:
+    """Each layer's attention settings as arrays with a leading layer axis,
+    to scan beside ``params["blocks"]``: ``window`` (L,) int32
+    (``NO_WINDOW`` for a full layer), ``inv_freq`` (L, head_dim/2) and
+    ``rope_scale`` (L,) float32.  None where every layer is alike: the
+    layers then use ``cfg.sliding_window`` and ``cfg.rope_theta``
+    statically."""
+    if cfg.uniform_layers:
+        return None
+    hd = cfg.resolved_head_dim
+    tables = [L.rope_table(hd, cfg.rope_for(cfg.layer_kind(i)))
+              for i in range(cfg.num_layers)]
+    windows = [cfg.layer_window(i) for i in range(cfg.num_layers)]
+    return {"window": jnp.asarray([NO_WINDOW if w is None else w
+                                   for w in windows], jnp.int32),
+            "inv_freq": jnp.asarray(np.stack([t[0] for t in tables])),
+            "rope_scale": jnp.asarray([t[1] for t in tables], jnp.float32)}
+
+
+def _rope(cfg: ModelConfig, la: Optional[Params], x: jax.Array,
+          positions: jax.Array) -> jax.Array:
+    """RoPE of one layer: its scanned table ``la``, or ``cfg.rope_theta``."""
+    if la is None:
+        return L.apply_rope(x, positions, cfg.rope_theta)
+    return L.apply_rope_freqs(x, positions, la["inv_freq"], la["rope_scale"])
+
+
+def _window(cfg: ModelConfig, la: Optional[Params]):
+    return cfg.sliding_window if la is None else la["window"]
+
+
+def split_experts(cfg: ModelConfig, blocks: Params
+                  ) -> Tuple[Params, Optional[Params]]:
+    """The stacked layer weights as a paged step's layer scan takes them:
+    ``(what the scan slices per layer, the MoE expert stacks)``.  The
+    expert stacks are not sliced: a slice of a stack that the
+    routed-expert kernel reads would be copied first (every expert of the
+    layer, 0.79 GB a layer at Mellum2's widths), so the scan slices the
+    layer's index (``ffn["layer"]``) in their place and ``join_experts``
+    hands the kernel the whole stacks.  A dense config: ``(blocks,
+    None)``, unchanged."""
+    if cfg.moe is None:
+        return blocks, None
+    ffn = dict(blocks["ffn"])
+    experts = {n: ffn.pop(n) for n in moe_mod.EXPERT_WEIGHTS}
+    ffn["layer"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    return dict(blocks, ffn=ffn), experts
+
+
+def join_experts(p: Params, experts: Optional[Params]) -> Params:
+    """One layer's scanned weights with the whole expert stacks back in."""
+    if experts is None:
+        return p
+    return dict(p, ffn=dict(p["ffn"], **experts))
+
+
+def _layer(params: Params, i: int, la: Optional[Params]):
+    """Layer ``i``'s weights and attention settings (unrolled paths)."""
+    pick = functools.partial(jax.tree.map, lambda a: a[i])
+    return pick(params["blocks"]), None if la is None else pick(la)
+
+
+# ---------------------------------------------------------------------------
 # forward pieces
 # ---------------------------------------------------------------------------
 
@@ -117,16 +195,17 @@ def _project_qkv(p: Params, cfg: ModelConfig, x: jax.Array
 
 
 def attention_block(p: Params, cfg: ModelConfig, x: jax.Array,
-                    positions: jax.Array, *, chunked: bool) -> jax.Array:
+                    positions: jax.Array, *, chunked: bool,
+                    la: Optional[Params] = None) -> jax.Array:
     """Full-sequence causal self-attention (train / prefill)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(p, cfg, x)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(cfg, la, q, positions)
+    k = _rope(cfg, la, k, positions)
     if chunked:
-        o = L.chunked_causal_attention(q, k, v, window=cfg.sliding_window)
+        o = L.chunked_causal_attention(q, k, v, window=_window(cfg, la))
     else:
-        o = L.causal_attention(q, k, v, window=cfg.sliding_window)
+        o = L.causal_attention(q, k, v, window=_window(cfg, la))
     return L.linear(o.reshape(b, s, -1), p["wo"])
 
 
@@ -138,23 +217,29 @@ def ffn_block(p: Params, cfg: ModelConfig, x: jax.Array
     return L.swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), jnp.float32(0.0)
 
 
-def mlp_residual(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    """``x + FFN(ffn_norm(x))`` (MoE aux loss dropped), under the ``mlp``
-    named scope so the profiler can tell the MLP's ops from the rest of
-    the step; the residual add sits inside, so a fusion rooted at it
-    still counts to the MLP."""
+def mlp_residual(p: Params, cfg: ModelConfig, x: jax.Array
+                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """``(x + FFN(ffn_norm(x)), ids)`` of a serving step, under the
+    ``mlp`` named scope so the profiler can tell the MLP's ops from the
+    rest of the step; the residual add sits inside, so a fusion rooted at
+    it still counts to the MLP.  An MoE FFN is the dropless
+    ``moe.moe_serve`` and ``ids`` its expert ids (..., k); None for a
+    dense FFN."""
     with jax.named_scope("mlp"):
-        f, _ = ffn_block(p["ffn"], cfg,
-                         L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps))
-        return x + f
+        xn = L.rmsnorm(x, p["ffn_norm"], cfg.rms_eps)
+        if cfg.moe is None:
+            return x + ffn_block(p["ffn"], cfg, xn)[0], None
+        f, ids = moe_mod.moe_serve(p["ffn"], cfg, xn)
+        return x + f, ids
 
 
 def block_forward(p: Params, cfg: ModelConfig, x: jax.Array,
-                  positions: jax.Array, *, chunked: bool
+                  positions: jax.Array, *, chunked: bool,
+                  la: Optional[Params] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     h = x + attention_block(p["attn"], cfg,
                             L.rmsnorm(x, p["attn_norm"], cfg.rms_eps),
-                            positions, chunked=chunked)
+                            positions, chunked=chunked, la=la)
     h = constrain_hidden(h)  # sequence-parallel boundary (no-op by default)
     f, aux = ffn_block(p["ffn"], cfg, L.rmsnorm(h, p["ffn_norm"], cfg.rms_eps))
     return constrain_hidden(h + f), aux
@@ -185,25 +270,27 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array, *,
 
     body = functools.partial(block_forward, cfg=cfg, positions=positions,
                              chunked=chunked)
+    per_layer = attention_layers(cfg)
     if scan_layers:
-        def scan_body(carry, layer_params):
+        def scan_body(carry, xs):
+            layer_params, la = xs
             fn = body
             if remat:
                 fn = jax.checkpoint(
-                    lambda p_, x_: body(p_, x=x_),
+                    lambda p_, x_, la_: body(p_, x=x_, la=la_),
                     policy=jax.checkpoint_policies.nothing_saveable)
-                y, aux = fn(layer_params, carry)
+                y, aux = fn(layer_params, carry, la)
             else:
-                y, aux = fn(layer_params, x=carry)
+                y, aux = fn(layer_params, x=carry, la=la)
             return y, aux
-        x, auxs = jax.lax.scan(scan_body, x, params["blocks"])
+        x, auxs = jax.lax.scan(scan_body, x, (params["blocks"], per_layer))
         aux = jnp.sum(auxs)
     else:
         aux = jnp.float32(0.0)
         n = cfg.num_layers
         for i in range(n):
-            layer_params = jax.tree.map(lambda a: a[i], params["blocks"])
-            x, a = body(layer_params, x=x)
+            layer_params, la = _layer(params, i, per_layer)
+            x, a = body(layer_params, x=x, la=la)
             aux = aux + a
     logits = unembed(params, cfg, x)
     if prefix:
@@ -240,7 +327,8 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 
 def prefill_block(p: Params, cfg: ModelConfig, x: jax.Array,
                   positions: jax.Array, max_len: int, *,
-                  chunked: bool) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+                  chunked: bool, la: Optional[Params] = None
+                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One layer of the cached prefill path → (x', (k_cache, v_cache)).
 
     Shared by the depth ``lax.scan`` in ``prefill`` and by the
@@ -251,14 +339,14 @@ def prefill_block(p: Params, cfg: ModelConfig, x: jax.Array,
     h = cfg.resolved_head_dim
     xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
     q, k, v = _project_qkv(p["attn"], cfg, xn)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(cfg, la, q, positions)
+    k = _rope(cfg, la, k, positions)
     if chunked:
-        o = L.chunked_causal_attention(q, k, v, window=cfg.sliding_window)
+        o = L.chunked_causal_attention(q, k, v, window=_window(cfg, la))
     else:
-        o = L.causal_attention(q, k, v, window=cfg.sliding_window)
+        o = L.causal_attention(q, k, v, window=_window(cfg, la))
     x = constrain_hidden(x + L.linear(o.reshape(b, s, -1), p["attn"]["wo"]))
-    x = constrain_hidden(mlp_residual(p, cfg, x))
+    x = constrain_hidden(mlp_residual(p, cfg, x)[0])
     kc = jnp.zeros((b, max_len, cfg.num_kv_heads, h), k.dtype)
     vc = jnp.zeros_like(kc)
     kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
@@ -277,18 +365,21 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.arange(s)
     chunked = s >= CHUNKED_ATTENTION_MIN_SEQ
 
-    def scan_body(carry, layer_params):
+    def scan_body(carry, xs):
+        layer_params, la = xs
         return prefill_block(layer_params, cfg, carry, positions, max_len,
-                             chunked=chunked)
+                             chunked=chunked, la=la)
 
-    x, (kcache, vcache) = jax.lax.scan(scan_body, x, params["blocks"])
+    x, (kcache, vcache) = jax.lax.scan(
+        scan_body, x, (params["blocks"], attention_layers(cfg)))
     logits = unembed(params, cfg, x[:, -1:, :])
     cache = {"k": kcache, "v": vcache, "pos": jnp.int32(s)}
     return cache, logits
 
 
 def decode_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
-                 vc: jax.Array, pos: jax.Array, positions: jax.Array
+                 vc: jax.Array, pos: jax.Array, positions: jax.Array,
+                 la: Optional[Params] = None
                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One layer of the single-token decode path → (x', (kc', vc')).
 
@@ -299,13 +390,13 @@ def decode_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     b = x.shape[0]
     xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
     q, k, v = _project_qkv(p["attn"], cfg, xn)
-    q = L.apply_rope(q, positions, cfg.rope_theta)
-    k = L.apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(cfg, la, q, positions)
+    k = _rope(cfg, la, k, positions)
     kc = jax.lax.dynamic_update_slice(kc, k, (0, pos, 0, 0))
     vc = jax.lax.dynamic_update_slice(vc, v, (0, pos, 0, 0))
-    o = L.decode_attention(q, kc, vc, pos + 1, window=cfg.sliding_window)
+    o = L.decode_attention(q, kc, vc, pos + 1, window=_window(cfg, la))
     x = x + L.linear(o.reshape(b, 1, -1), p["attn"]["wo"])
-    return mlp_residual(p, cfg, x), (kc, vc)
+    return mlp_residual(p, cfg, x)[0], (kc, vc)
 
 
 def decode_step(params: Params, cfg: ModelConfig, cache: Params,
@@ -317,19 +408,19 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
     positions = jnp.full((b, 1), pos, jnp.int32)
 
     def scan_body(carry, scan_in):
-        p, kc, vc = scan_in
-        return decode_block(p, cfg, carry, kc, vc, pos, positions)
+        p, kc, vc, la = scan_in
+        return decode_block(p, cfg, carry, kc, vc, pos, positions, la)
 
     x, (kcache, vcache) = jax.lax.scan(
-        scan_body, x, (params["blocks"], cache["k"], cache["v"]))
+        scan_body, x, (params["blocks"], cache["k"], cache["v"],
+                       attention_layers(cfg)))
     logits = unembed(params, cfg, x)
     return {"k": kcache, "v": vcache, "pos": pos + 1}, logits
 
 
 def decode_core_rows(p: Params, cfg: ModelConfig, x: jax.Array,
                      kc: jax.Array, vc: jax.Array, pos: jax.Array, *,
-                     emit_cache: bool = True
-                     ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+                     emit_cache: bool = True, la: Optional[Params] = None):
     """Shared per-layer math for PER-ROW-position decode.
 
     Identical math to :func:`decode_block`, except every batch row carries
@@ -342,32 +433,41 @@ def decode_core_rows(p: Params, cfg: ModelConfig, x: jax.Array,
     pool carries them forward); ``emit_cache=False`` returns just the new
     token's (k, v) rows — the paged path scatters those into its block
     arena instead of materializing a dense cache copy.
+
+    ``la``: this layer's scanned attention settings (``attention_layers``),
+    None where every layer is alike.  An MoE layer's expert ids (B, 1,
+    k) come back as a third element of the cache tuple.
     """
     b = x.shape[0]
     positions = pos[:, None]                         # (B, 1)
     with jax.named_scope("attn"):
         xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
         q, k, v = _project_qkv(p["attn"], cfg, xn)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(cfg, la, q, positions)
+        k = _rope(cfg, la, k, positions)
         rows = jnp.arange(b)
         kc = kc.at[rows, pos].set(k[:, 0].astype(kc.dtype))
         vc = vc.at[rows, pos].set(v[:, 0].astype(vc.dtype))
-        o = L.decode_attention(q, kc, vc, pos + 1, window=cfg.sliding_window)
+        o = L.decode_attention(q, kc, vc, pos + 1, window=_window(cfg, la))
         x = x + L.linear(o.reshape(b, 1, -1), p["attn"]["wo"])
     out = (kc, vc) if emit_cache else (k[:, 0], v[:, 0])
-    return mlp_residual(p, cfg, x), out
+    x, ids = mlp_residual(p, cfg, x)
+    return x, out if ids is None else out + (ids,)
 
 
 def decode_block_rows(p: Params, cfg: ModelConfig, x: jax.Array,
                       kc: jax.Array, vc: jax.Array, pos: jax.Array,
+                      la: Optional[Params] = None
                       ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One layer of the per-row-position decode path → (x', (kc', vc'))."""
-    return decode_core_rows(p, cfg, x, kc, vc, pos, emit_cache=True)
+    x, out = decode_core_rows(p, cfg, x, kc, vc, pos, emit_cache=True,
+                              la=la)
+    return x, out[:2]
 
 
 def extend_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
-                 vc: jax.Array, pos0: jax.Array, positions: jax.Array
+                 vc: jax.Array, pos0: jax.Array, positions: jax.Array,
+                 la: Optional[Params] = None
                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One layer of the MULTI-token extend path (chunked prefill).
 
@@ -385,20 +485,21 @@ def extend_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     with jax.named_scope("attn"):
         xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
         q, k, v = _project_qkv(p["attn"], cfg, xn)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(cfg, la, q, positions)
+        k = _rope(cfg, la, k, positions)
         kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
                                           (0, pos0, 0, 0))
         vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
                                           (0, pos0, 0, 0))
         o = L.causal_attention(q, kc, vc, q_offset=pos0,
-                               window=cfg.sliding_window)
+                               window=_window(cfg, la))
         x = x + L.linear(o.reshape(b, s, -1), p["attn"]["wo"])
-    return mlp_residual(p, cfg, x), (k, v)
+    return mlp_residual(p, cfg, x)[0], (k, v)
 
 
 def verify_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
-                 vc: jax.Array, pos: jax.Array, positions: jax.Array
+                 vc: jax.Array, pos: jax.Array, positions: jax.Array,
+                 la: Optional[Params] = None
                  ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One layer of the PER-ROW multi-token verify path (speculative decode).
 
@@ -418,15 +519,15 @@ def verify_block(p: Params, cfg: ModelConfig, x: jax.Array, kc: jax.Array,
     with jax.named_scope("attn"):
         xn = L.rmsnorm(x, p["attn_norm"], cfg.rms_eps)
         q, k, v = _project_qkv(p["attn"], cfg, xn)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
+        q = _rope(cfg, la, q, positions)
+        k = _rope(cfg, la, k, positions)
         rows = jnp.arange(b)
         kc = kc.at[rows[:, None], positions].set(k.astype(kc.dtype))
         vc = vc.at[rows[:, None], positions].set(v.astype(vc.dtype))
         o = L.causal_attention(q, kc, vc, q_offset=pos,
-                               window=cfg.sliding_window)
+                               window=_window(cfg, la))
         x = x + L.linear(o.reshape(b, c, -1), p["attn"]["wo"])
-    return mlp_residual(p, cfg, x), (k, v)
+    return mlp_residual(p, cfg, x)[0], (k, v)
 
 
 def decode_step_rows(params: Params, cfg: ModelConfig, cache: Params,
@@ -442,10 +543,11 @@ def decode_step_rows(params: Params, cfg: ModelConfig, cache: Params,
     pos = cache["pos"]
 
     def scan_body(carry, scan_in):
-        p, kc, vc = scan_in
-        return decode_block_rows(p, cfg, carry, kc, vc, pos)
+        p, kc, vc, la = scan_in
+        return decode_block_rows(p, cfg, carry, kc, vc, pos, la)
 
     x, (kcache, vcache) = jax.lax.scan(
-        scan_body, x, (params["blocks"], cache["k"], cache["v"]))
+        scan_body, x, (params["blocks"], cache["k"], cache["v"],
+                       attention_layers(cfg)))
     logits = unembed(params, cfg, x)
     return {"k": kcache, "v": vcache, "pos": pos + 1}, logits

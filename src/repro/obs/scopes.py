@@ -8,6 +8,11 @@ part of the work:
   relayout into the dense per-layer view;
 * ``attn`` — ``attn_norm`` through ``wo``, the residual add included;
 * ``mlp`` — ``ffn_norm`` through ``w_down``, the residual add included;
+  in an MoE layer (``models/moe.py`` ``moe_serve``) two scopes inside it
+  take their parts:
+* ``moe_route`` — the router's logits, softmax and top-k;
+* ``moe_experts`` — the routed experts (the routed-expert kernel, or the
+  grouped products of a prefill chunk) and their weighted combine;
 * ``unembed`` — final norm, logits and argmax;
 * ``kv_write`` — the arena scatter of the new K/V.
 
@@ -39,7 +44,8 @@ import collections
 import re
 from typing import Dict, List, Tuple
 
-SCOPES = ("kv_gather", "attn", "mlp", "unembed", "kv_write")
+SCOPES = ("kv_gather", "attn", "mlp", "moe_route", "moe_experts",
+          "unembed", "kv_write")
 
 _HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")   # unindented
 _INST = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")

@@ -68,9 +68,12 @@ class StepOutput(NamedTuple):
     ``next_token``  — (B, 1) int32 device-side argmax when the backend
                       computes it in-graph (the paper's "token readback"
                       regime, App. H); ``None`` when only logits exist.
+    ``routed``      — (L, B, k) int32 expert ids of an MoE decode step,
+                      every layer's; read back only for a live tracer.
     """
     logits: jax.Array
     next_token: Optional[jax.Array] = None
+    routed: Optional[jax.Array] = None
 
 
 class MultiStepOutput(NamedTuple):

@@ -72,6 +72,14 @@ class DistBackend(ExecutionBackend):
             raise ValueError(
                 f"dist backend supports dense transformers only, got "
                 f"family={cfg.family!r} (moe={cfg.moe is not None})")
+        if not cfg.uniform_layers:
+            # the stage scans apply one static window and RoPE base to
+            # every layer; per-layer settings would have to be sharded
+            # over the stages beside the blocks
+            raise ValueError(
+                f"dist backend needs every layer alike; {cfg.name} mixes "
+                f"attention kinds {sorted(set(cfg.layer_types))} — serve it "
+                "with the model backend")
         devs = jax.devices()
         n_stages = stages or _auto_stages(cfg.num_layers, len(devs))
         if cfg.num_layers % n_stages:

@@ -267,12 +267,12 @@ class ModelBackend(ExecutionBackend):
                 copies = sum(pg.ensure_writable(s, int(pg.pos[s]),
                                                 int(pg.pos[s]) + 1)
                              for s in slots)
-            ak, av, logits, nxt = self._enqueue_paged(
+            ak, av, logits, nxt, *routed = self._enqueue_paged(
                 self._jit_decode_paged, pg, tokens, copies, "decode_batch")
             with self._phase("adopt"):
                 pg.pool.set_arena(ak, av)
                 pg.advance(slots)
-        return bstate, StepOutput(logits, nxt)
+        return bstate, StepOutput(logits, nxt, *routed)
 
     def _enqueue_paged(self, fn, pg, tokens, copies: int, op: str):
         """Upload the block table, positions and tokens, then make the

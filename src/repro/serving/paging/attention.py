@@ -22,6 +22,14 @@ contiguous ``max_len`` rows:
   into blocks the very next chunk (or decode) overwrites, and the causal
   mask keeps them unreadable meanwhile.
 
+Each step is one ``lax.scan`` over the layers; where layers differ in
+attention kind (sliding or full window, plain or YaRN RoPE) the scan
+carries each layer's settings (``transformer.attention_layers``) beside
+its weights and gathered KV, so the step stays one dispatch.  An MoE
+layer's expert stacks are not sliced by the scan, which carries the
+layer's index instead (``transformer.split_experts``): the routed-expert
+kernel reads the stacks in place.
+
 The gathered dense view is position-identical to the dense cache layout
 (table entry ``i`` covers logical tokens ``[i*block_size, (i+1)*block_size)``),
 so the math — and the greedy token stream — matches the dense path
@@ -87,28 +95,34 @@ def write_kv(arena_k: jax.Array, arena_v: jax.Array, table: jax.Array,
 
 def decode_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
                       arena_v: jax.Array, table: jax.Array, pos: jax.Array,
-                      tokens: jax.Array
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+                      tokens: jax.Array):
     """One batched decode cycle through per-slot block tables.
 
     ``table`` (S, W) int32, ``pos`` (S,) int32, ``tokens`` (S, 1) int32 →
     (arena_k', arena_v', logits (S, 1, V), next_token (S, 1)).  Same single
     dispatch as the dense rows path; only the cache plumbing differs.
+    An MoE config's step has a fifth output, every layer's expert ids
+    (L, S, k) int32, which a live tracer reads back with the tokens.
     """
     x = params["embed"][tokens]
     kd = gather_blocks(arena_k, table, cfg.num_kv_heads)
     vd = gather_blocks(arena_v, table, cfg.num_kv_heads)
+    blocks, experts = transformer.split_experts(cfg, params["blocks"])
 
     def body(carry, xs):
-        p, kc, vc = xs
-        return transformer.decode_core_rows(p, cfg, carry, kc, vc, pos,
-                                            emit_cache=False)
+        p, kc, vc, la = xs
+        return transformer.decode_core_rows(
+            transformer.join_experts(p, experts), cfg, carry, kc, vc, pos,
+            emit_cache=False, la=la)
 
-    x, (knew, vnew) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
+    x, new = jax.lax.scan(body, x, (blocks, kd, vd,
+                                    transformer.attention_layers(cfg)))
     logits, nxt = _unembed_argmax(params, cfg, x)
     # each slot's new token lands in its current block, which
     # ensure_writable made exclusively ours
-    arena_k, arena_v = write_kv(arena_k, arena_v, table, pos, knew, vnew)
+    arena_k, arena_v = write_kv(arena_k, arena_v, table, pos, new[0], new[1])
+    if cfg.moe is not None:
+        return arena_k, arena_v, logits, nxt, new[2][:, :, 0]
     return arena_k, arena_v, logits, nxt
 
 
@@ -137,12 +151,16 @@ def verify_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     c = tokens.shape[1]
     positions = pos[:, None] + jnp.arange(c)       # (S, C)
 
-    def body(carry, xs):
-        p, kc, vc = xs
-        return transformer.verify_block(p, cfg, carry, kc, vc, pos,
-                                        positions)
+    blocks, experts = transformer.split_experts(cfg, params["blocks"])
 
-    x, (kch, vch) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
+    def body(carry, xs):
+        p, kc, vc, la = xs
+        return transformer.verify_block(transformer.join_experts(p, experts),
+                                        cfg, carry, kc, vc, pos, positions,
+                                        la)
+
+    x, (kch, vch) = jax.lax.scan(body, x, (blocks, kd, vd,
+                                           transformer.attention_layers(cfg)))
     logits, nxt = _unembed_argmax(params, cfg, x)
     # the same scatter decode_step_paged makes, batched over the span axis
     arena_k, arena_v = write_kv(arena_k, arena_v, table, positions, kch,
@@ -170,12 +188,16 @@ def extend_step_paged(params, cfg: ModelConfig, arena_k: jax.Array,
     c = tokens.shape[1]
     positions = pos0 + jnp.arange(c)
 
-    def body(carry, xs):
-        p, kc, vc = xs
-        return transformer.extend_block(p, cfg, carry, kc, vc, pos0,
-                                        positions)
+    blocks, experts = transformer.split_experts(cfg, params["blocks"])
 
-    x, (kch, vch) = jax.lax.scan(body, x, (params["blocks"], kd, vd))
+    def body(carry, xs):
+        p, kc, vc, la = xs
+        return transformer.extend_block(transformer.join_experts(p, experts),
+                                        cfg, carry, kc, vc, pos0, positions,
+                                        la)
+
+    x, (kch, vch) = jax.lax.scan(body, x, (blocks, kd, vd,
+                                           transformer.attention_layers(cfg)))
     x_last = jax.lax.dynamic_slice_in_dim(x, valid - 1, 1, axis=1)
     logits, nxt = _unembed_argmax(params, cfg, x_last)
     # padded positions land in writable blocks and are overwritten before

@@ -934,14 +934,23 @@ class Scheduler:
         with tr.span("readback", track="scheduler", overlapped=overlapped):
             t0 = time.perf_counter()
             # one host readback per CYCLE (not per slot) in the greedy
-            # token-readback regime: a (num_slots,) int32 vector
-            nxt = (np.asarray(out.next_token, np.int32)
-                   if out.next_token is not None else None)
+            # token-readback regime: a (num_slots,) int32 vector; a live
+            # tracer takes an MoE step's expert ids in the same transfer
+            routed = None
+            if tr.enabled and out.routed is not None \
+                    and out.next_token is not None:
+                nxt, routed = jax.device_get((out.next_token, out.routed))
+                nxt = np.asarray(nxt, np.int32)
+            else:
+                nxt = (np.asarray(out.next_token, np.int32)
+                       if out.next_token is not None else None)
             dt = time.perf_counter() - t0
         if overlapped:
             st.overlap_readback_s += dt
         else:
             st.sync_readback_s += dt
+        if routed is not None:
+            self._count_routed(np.asarray(routed)[:, list(slots)])
         with tr.span("sample_emit", track="scheduler", slots=len(slots)):
             for s in slots:
                 a = active[s]
@@ -956,6 +965,20 @@ class Scheduler:
                                req=a.req.request_id, n_new=len(a.tokens))
                     del active[s]
         return bstate
+
+    def _count_routed(self, ids: np.ndarray) -> None:
+        """Counters of one MoE decode step from its expert ids (L, rows,
+        k): ``moe.experts_touched``, the (layer, expert) pairs whose
+        weights the step read, and ``moe.max_expert_rows``, the most rows
+        one expert took in one layer."""
+        touched, most = 0, 0
+        for layer in ids:
+            _, counts = np.unique(layer, return_counts=True)
+            touched += len(counts)
+            most = max(most, int(counts.max(initial=0)))
+        self.tracer.counter("moe.experts_touched", touched,
+                            track="scheduler")
+        self.tracer.counter("moe.max_expert_rows", most, track="scheduler")
 
     def _async_safe(self, active: Dict[int, "_Active"]) -> bool:
         """True when deferring the readback cannot change observable

@@ -244,3 +244,15 @@ def test_dist_backend_rejects_unsupported_family():
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="dense"):
         create_backend("dist", model, params, batch=1, max_len=8)
+
+
+def test_dist_backend_refuses_mixed_attention_layers():
+    """The stage scans apply one window and RoPE base to every layer, so
+    a config that mixes sliding and full layers is refused by name."""
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    from repro.serving.backends.dist import DistBackend
+    cfg = get_smoke_config("qwen2-1.5b").replace(
+        layer_types=("sliding", "full"), sliding_window=8)
+    with pytest.raises(ValueError, match="every layer alike"):
+        DistBackend(build_model(cfg), None)

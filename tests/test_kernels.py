@@ -12,6 +12,8 @@ from repro.kernels.fused_kv_proj.ref import kv_proj_ref
 from repro.kernels.fused_mlp.ref import fused_mlp_ref
 from repro.kernels.fused_rmsnorm.ref import rmsnorm_ref
 from repro.kernels.fused_softmax.ref import softmax_ref
+from repro.kernels.routed_experts.ops import expert_slots, routed_experts
+from repro.kernels.routed_experts.ref import routed_experts_ref
 from repro.kernels.tiled_matmul.ref import matmul_ref
 
 _TOL = {jnp.float32: dict(atol=2e-3, rtol=2e-3),
@@ -131,3 +133,47 @@ def test_use_interpret_only_on_cpu(monkeypatch):
             common.use_interpret()
     finally:
         common.use_interpret.cache_clear()
+
+
+def _routing(key, rows, k, n_experts):
+    """Each row's k distinct experts (rows 0 and 1 share two) and weights
+    that sum to 1."""
+    ks = jax.random.split(key, rows + 1)
+    ids = jnp.stack([jax.random.permutation(ks[r], n_experts)[:k]
+                     for r in range(rows)]).astype(jnp.int32)
+    if rows > 1:
+        ids = ids.at[1, :2].set(ids[0, :2])
+    p = jax.nn.softmax(jax.random.normal(ks[-1], (rows, k)), -1)
+    return ids, p
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("rows,held", [(1, None), (3, None), (4, (8, 24))])
+def test_routed_experts(rng, rows, held, dtype):
+    """The kernel against the gather oracle: one row, rows sharing
+    experts, and a held share of the experts (the others' assignments
+    left out)."""
+    n_e, k, d, f = 32, 4, 128, 256
+    ks = jax.random.split(rng, 5)
+    x = jax.random.normal(ks[0], (rows, d)).astype(dtype)
+    w = [(jax.random.normal(kk, shape) / np.sqrt(shape[1])).astype(dtype)
+         for kk, shape in zip(ks[1:4], [(n_e, d, f), (n_e, d, f),
+                                        (n_e, f, d)])]
+    ids, p = _routing(ks[4], rows, k, n_e)
+    lo, hi = held or (0, n_e)
+    w = [a[lo:hi] for a in w]
+    got = routed_experts(x, ids, p, *w, lo=lo)
+    want = routed_experts_ref(x, ids, p, *w, lo=lo)
+    assert got.dtype == jnp.float32
+    _cmp(got, want, dtype)
+
+
+def test_expert_slots_read_each_touched_expert_once():
+    ids = jnp.array([[5, 2], [2, 9], [40, 5]], jnp.int32)
+    p = jnp.array([[0.75, 0.25], [0.5, 0.5], [0.875, 0.125]])
+    slots, count, gates = expert_slots(ids, p, n_held=16, lo=0, n_slots=6)
+    assert int(count[0]) == 3                       # 40 is not held
+    assert slots.tolist() == [2, 5, 9, 9, 9, 9]     # pads repeat the last
+    np.testing.assert_allclose(gates, [[0.25, 0.5, 0], [0.75, 0, 0.125],
+                                       [0, 0.5, 0], [0, 0, 0], [0, 0, 0],
+                                       [0, 0, 0]])

@@ -105,3 +105,56 @@ def test_cross_entropy_mask(rng):
     full = L.cross_entropy_loss(logits[:, :2], labels[:, :2])
     masked = L.cross_entropy_loss(logits, labels, m)
     np.testing.assert_allclose(float(full), float(masked), rtol=1e-6)
+
+
+# -- per-layer rotary tables and windows ------------------------------------
+
+def test_yarn_freqs_match_a_hand_table():
+    """YaRN at head_dim 16, θ 5e5, factor 16 over 8192, β 32/1.  The ramp
+    ends are floor(16·ln(8192/(32·2π)) / (2·ln 5e5)) = floor(2.26) = 2 and
+    ceil(16·ln(8192/2π) / (2·ln 5e5)) = ceil(4.37) = 5: dims 0-2 keep
+    5e5^(-i/8), dims 5-7 take it over 16, dims 3 and 4 mix the two 2:1
+    and 1:2."""
+    from repro.configs.base import RopeConfig
+    plain = [1.0, 0.19392275, 0.037606031, 7.2926647e-3, 1.4142136e-3,
+             2.7424818e-4, 5.3182959e-5, 1.0313385e-5]
+    want = plain[:3] + [2 / 3 * plain[3] + 1 / 3 * plain[3] / 16,
+                        1 / 3 * plain[4] + 2 / 3 * plain[4] / 16] \
+        + [f / 16 for f in plain[5:]]
+    rope = RopeConfig(500000.0, 16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+    inv, scale = L.rope_table(16, rope)
+    np.testing.assert_allclose(inv, want, rtol=1e-6)
+    assert scale == 1.2772588722239782
+    # without an attention factor YaRN's own: 0.1·ln(16) + 1
+    _, scale = L.rope_table(16, rope._replace(attention_factor=None))
+    assert scale == pytest.approx(1.2772588722239782)
+
+
+def test_plain_rope_table_is_the_theta_path(rng):
+    from repro.configs.base import RopeConfig
+    inv, scale = L.rope_table(16, RopeConfig(1e6))
+    np.testing.assert_allclose(inv, L.rope_freqs(16, 1e6), rtol=1e-7)
+    x = jax.random.normal(rng, (2, 5, 3, 16))
+    pos = jnp.arange(5) + 7
+    np.testing.assert_allclose(
+        L.apply_rope_freqs(x, pos, jnp.asarray(inv), scale),
+        L.apply_rope(x, pos, 1e6), atol=1e-6)
+    # the scale multiplies the rotated vector
+    np.testing.assert_allclose(
+        L.apply_rope_freqs(x, pos, jnp.asarray(inv), 2.0),
+        2.0 * L.apply_rope(x, pos, 1e6), atol=1e-5)
+
+
+def test_a_traced_window_masks_as_a_static_one(rng):
+    """The layer scan hands each layer its window as an array: the same
+    mask as the static int, and a window past every position is none."""
+    q, k, v = _qkv(rng, 1, 40, 4, 2, 8)
+    np.testing.assert_allclose(
+        L.causal_attention(q, k, v, window=jnp.int32(13)),
+        L.causal_attention(q, k, v, window=13), atol=1e-6)
+    np.testing.assert_allclose(
+        L.causal_attention(q, k, v, window=jnp.int32(1 << 30)),
+        L.causal_attention(q, k, v), atol=1e-6)
+    np.testing.assert_allclose(
+        L.decode_attention(q[:, -1:], k, v, 40, window=jnp.int32(13)),
+        L.decode_attention(q[:, -1:], k, v, 40, window=13), atol=1e-6)

@@ -131,3 +131,48 @@ def test_moe_capacity_properties():
         assert c * e >= t * k  # capacity_factor ≥ 1 ⇒ no forced drops
 
     prop()
+
+
+# -- per-layer attention kinds ------------------------------------------------
+
+def test_layer_types_are_checked_and_kept_by_reduction():
+    from repro.configs import get_config
+    cfg = get_config("mellum2-12b-a2.5b")
+    assert not cfg.uniform_layers
+    assert [cfg.layer_window(i) for i in range(4)] == [1024] * 3 + [None]
+    assert cfg.rope_for("full").factor == 16.0
+    assert cfg.rope_for("sliding") == (500000.0,) + (None,) * 5
+    # a smoke size keeps one whole period of the pattern, and top-k of more
+    small = get_smoke_config("mellum2-12b-a2.5b", layers=2)
+    assert small.layer_types == ("sliding",) * 3 + ("full",)
+    assert small.moe.top_k < small.moe.num_experts
+    for bad in (dict(layer_types=("sliding",) * 7),
+                dict(layer_types=("local",) * 8),
+                dict(sliding_window=None)):
+        with pytest.raises(ValueError):
+            cfg.replace(**bad)
+    # every registered config without the fields is uniform
+    assert all(c.uniform_layers for c in ASSIGNED.values())
+
+
+def test_mixed_layers_serve_with_the_dropless_layer_and_decode_consistently(
+        rng, monkeypatch):
+    """prefill(S−1) + decode_step(last) ≡ forward(S)[-1] for the mixed
+    sliding/full YaRN MoE config, past the window (smoke window 32), with
+    the training forward's MoE swapped for the serving layer it is
+    compared with (capacity would drop tokens the serving path keeps)."""
+    from repro.models import moe, transformer
+    cfg = get_smoke_config("mellum2-12b-a2.5b")
+    model = build_model(cfg)
+    params = model.init_params(rng)
+    toks = jax.random.randint(rng, (1, 48), 0, cfg.vocab_size, jnp.int32)
+    cache, _ = model.prefill(params, {"tokens": toks[:, :-1]}, 64)
+    _, dec = model.decode_step(params, cache, toks[:, -1:])
+    monkeypatch.setattr(moe, "moe_ffn", lambda p, c, x: (
+        moe.moe_serve(p, c, x)[0], jnp.float32(0)))
+    full, _ = transformer.forward(params, cfg, toks)
+    unrolled, _ = transformer.forward(params, cfg, toks, scan_layers=False)
+    np.testing.assert_allclose(np.asarray(dec[:, 0]),
+                               np.asarray(full[:, -1]), atol=2e-4)
+    np.testing.assert_allclose(np.asarray(unrolled), np.asarray(full),
+                               atol=2e-4)

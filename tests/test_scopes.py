@@ -14,6 +14,8 @@ from repro.obs.scopes import SCOPES, _parse, op_scopes, scope_of
 from repro.serving import (InferenceSession, Scheduler, ServeRequest,
                            create_backend)
 
+MOE_SCOPES = ("moe_route", "moe_experts")
+
 HLO = """HloModule jit_step, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
 
 %fused_computation.1 (param_0: f32[8]) -> f32[8] {
@@ -78,7 +80,8 @@ def test_compiled_paged_programs_name_all_five_scopes(traced_backend):
     assert all(len(texts) == 1 for texts in progs.values())
     for name, (text,) in progs.items():
         m = op_scopes(text)
-        assert set(m.values()) == set(SCOPES), name
+        # a dense model's steps: every scope but the MoE layer's two
+        assert set(m.values()) == set(SCOPES) - set(MOE_SCOPES), name
         # the fused-computation fallback carries some of the step
         comps = _parse(text)
         nested = {i[4] for insts in comps.values() for i in insts if i[4]}
@@ -133,3 +136,68 @@ def test_compiled_text_names_the_ops_a_trace_shows(traced_backend,
     assert set(ops) == set(names)
     for prog, seen in ops.items():
         assert seen <= names[prog], prog
+
+
+# -- an MoE layer's two scopes and its routing counters ----------------------
+
+def _moe_sched(tracer=None):
+    cfg = get_smoke_config("mellum2-12b-a2.5b")
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    backend = create_backend("model", model, params, batch=1, max_len=64)
+    kw = {"tracer": tracer} if tracer is not None else {}
+    sched = Scheduler(InferenceSession(backend), num_slots=1,
+                      kv_layout="paged", prefill_chunk=8, block_size=8, **kw)
+    return cfg, backend, sched
+
+
+@pytest.fixture(scope="module")
+def traced_moe():
+    tracer = Tracer()
+    cfg, backend, sched = _moe_sched(tracer)
+    rng = np.random.default_rng(5)
+    for _ in range(2):
+        p = rng.integers(0, cfg.vocab_size, (1, 12)).astype(np.int32)
+        sched.submit(ServeRequest(prompt=p, max_new_tokens=4))
+    sched.run()
+    return cfg, backend, tracer, sched
+
+
+def test_moe_programs_name_the_moe_scopes(traced_moe):
+    """Decode and extend of the mixed sliding/full MoE config: every
+    scope, the router's ``moe_route`` and the experts' ``moe_experts``
+    among them, inside the one program each."""
+    _, backend, _, _ = traced_moe
+    progs = backend.compiled_programs()
+    assert set(progs) == {"jit__decode_paged", "jit__extend_paged"}
+    for name, (text,) in progs.items():
+        assert set(op_scopes(text).values()) == set(SCOPES), name
+
+
+def test_moe_counters_count_each_decode_steps_experts(traced_moe):
+    """One row routes to k distinct experts in each layer: every decode
+    step reads L·k experts, and no expert takes more than the one row."""
+    cfg, _, tracer, _ = traced_moe
+    touched = [e.args["value"] for e in tracer.events()
+               if e.name == "moe.experts_touched"]
+    most = [e.args["value"] for e in tracer.events()
+            if e.name == "moe.max_expert_rows"]
+    cycles = tracer.count("decode_cycle")
+    assert cycles and len(touched) == len(most) == cycles
+    assert set(touched) == {cfg.num_layers * cfg.moe.top_k}
+    assert set(most) == {1}
+
+
+def test_moe_ids_cross_only_for_a_live_tracer(monkeypatch):
+    """Without a tracer the scheduler reads back the tokens alone: no
+    ``device_get`` of the expert ids, and no counter."""
+    import repro.serving.session as session
+
+    def refuse(*_):
+        raise AssertionError("expert ids read back without a tracer")
+    monkeypatch.setattr(session.jax, "device_get", refuse)
+    cfg, _, sched = _moe_sched()
+    sched.submit(ServeRequest(prompt=np.arange(1, 11, dtype=np.int32)
+                              .reshape(1, -1), max_new_tokens=4,
+                              request_id="r"))
+    assert sched.run()["r"].n_new == 4

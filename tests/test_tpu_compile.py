@@ -5,8 +5,9 @@ The TPU compiler is installed alongside JAX, and it compiles for a chip
 that is described rather than attached.  Interpret mode (what every other
 kernel test runs) cannot see Mosaic's tiling and VMEM rules; these
 compiles can, at Qwen2.5-0.5B widths in bf16: d_model 896, d_ff 4864,
-14 query heads over 2 KV heads of head_dim 64.  The same compiles show
-the layout copies XLA puts around the paged KV arena.
+14 query heads over 2 KV heads of head_dim 64 (the routed-expert kernel
+at Mellum2's: 64 experts of 2304 x 896).  The same compiles show the
+layout copies XLA puts around the paged KV arena.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and the suite's workers all
@@ -30,6 +31,7 @@ from repro.kernels.fused_kv_proj.kernel import kv_proj_pallas
 from repro.kernels.fused_mlp.kernel import fused_mlp_pallas
 from repro.kernels.fused_rmsnorm.kernel import rmsnorm_pallas
 from repro.kernels.fused_softmax.kernel import softmax_pallas
+from repro.kernels.routed_experts.kernel import routed_experts_pallas
 from repro.kernels.tiled_matmul.kernel import matmul_pallas
 from repro.models import build_model
 from repro.serving.backends.dist import DistBackend
@@ -37,6 +39,7 @@ from repro.serving.paging import (arena_shape, decode_step_paged,
                                   extend_step_paged)
 
 D, F, H, KV, HD = 896, 4864, 14, 2, 64
+ME, MD, MF = 64, 2304, 896       # Mellum2: experts, d_model, expert width
 ROWS = 128          # one MXU row block of tokens
 SLOTS, S = 4, 2048  # decode: scheduler slots × cache length
 
@@ -53,8 +56,14 @@ CASES = {
     "decode_attention": (decode_attention_pallas, [
         ((SLOTS, KV, H // KV, HD), "bf16"), ((SLOTS, KV, S, HD), "bf16"),
         ((SLOTS, KV, S, HD), "bf16"), ((1,), "i32")]),
+    # one decode row's 8 routed experts (rows padded to 8), of one layer
+    # of the 8-layer stacks
+    "routed_experts": (routed_experts_pallas, [
+        ((1,), "i32"), ((8,), "i32"), ((1,), "i32"), ((8, MD), "bf16"),
+        ((8, 8, 1), "f32"), ((8, ME, MD, MF), "bf16"),
+        ((8, ME, MD, MF), "bf16"), ((8, ME, MF, MD), "bf16")]),
 }
-_DTYPES = {"bf16": jnp.bfloat16, "i32": jnp.int32}
+_DTYPES = {"bf16": jnp.bfloat16, "i32": jnp.int32, "f32": jnp.float32}
 
 
 @pytest.fixture(scope="module")
@@ -254,3 +263,69 @@ def test_dist_paged_program_keeps_the_arena_in_place(arch, program, topo):
             if op.startswith(_COLLECTIVES)]
     assert coll and not [c for c in coll if c[0].startswith("all-gather")]
     assert max(b for _, b in coll) < arena, coll
+
+
+# bytes of every copy in each Qwen decode program before per-layer
+# attention settings and MoE layers joined the scans (62.9 and 73.4 MB)
+_QWEN_DECODE_COPY_BYTES = {"qwen2.5-0.5b": 62_917_388,
+                           "qwen2.5-1.5b": 73_404_188}
+
+
+@pytest.mark.parametrize("arch", sorted(_QWEN_DECODE_COPY_BYTES))
+def test_qwen_decode_copies_are_unchanged(arch, one_chip):
+    """A config whose layers are all alike compiles to the program it
+    did: the same copies, to the byte."""
+    _, text = _paged_program(arch, "decode", one_chip)
+    assert sum(b for _, b, _, _ in _layout_copies(text)) \
+        == _QWEN_DECODE_COPY_BYTES[arch]
+
+
+def test_mellum_decode_reads_experts_in_place(one_chip, monkeypatch):
+    """The Mellum2 decode step at 2 layers (one sliding, one full YaRN),
+    over a 64-block table (1,024 positions, the window): the
+    routed-expert kernel is in it, no copy is as large as one expert's
+    matrix (2304 x 896 bf16, 4.1 MB), and no op but the loop's plumbing
+    holds an array of expert matrices (the scan's slice of a layer's
+    stack, 0.79 GB at 64 experts, would be one), so no expert weight is
+    gathered or copied before the kernel reads it.  The kernel's
+    interpret switch follows the CPU this compiles on, so the test sets
+    it for the chip."""
+    from repro.kernels.routed_experts import ops
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    cfg = get_config("mellum2-12b-a2.5b").replace(
+        num_layers=2, layer_types=("sliding", "full"))
+    ak, av, _, pos, tok = _paged_args(cfg, "decode", one_chip, one_chip)
+    table = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, ak, av, *a: decode_step_paged(p, cfg, ak, av,
+                                                           *a),
+                   donate_argnums=(1, 2))
+    text = step.lower(_params(cfg, one_chip), ak, av, table, pos,
+                      tok).compile().as_text()
+    assert text.count("tpu_custom_call") == 1       # the scan body's one
+    copies = _check_arena_copies("mellum2-12b-a2.5b", "decode", text)
+    assert max(b for _, b, _, _ in copies) < MD * MF * 2, copies
+    held = [(op, dims) for _, dims, _, op in _INSTR.findall(text)
+            if _dims(dims)[-2:] in ((MD, MF), (MF, MD))
+            and op not in ("parameter", "get-tuple-element", "tuple",
+                           "bitcast", "while")]
+    assert not held, held
+
+
+def test_mellum_extend_reads_experts_in_place(one_chip):
+    """The Mellum2 prefill chunk at 2 layers: its grouped products run
+    over the expert stacks flattened across layers, so no op but the
+    loop's plumbing holds an array of expert matrices (a slice of one
+    layer's stack, 0.79 GB at 64 experts, would be one)."""
+    cfg = get_config("mellum2-12b-a2.5b").replace(
+        num_layers=2, layer_types=("sliding", "full"))
+    step = jax.jit(lambda p, ak, av, *a: extend_step_paged(p, cfg, ak, av,
+                                                           *a),
+                   donate_argnums=(1, 2))
+    text = step.lower(_params(cfg, one_chip), *_paged_args(
+        cfg, "extend", one_chip, one_chip)).compile().as_text()
+    assert "ragged" in text
+    held = [(op, dims) for _, dims, _, op in _INSTR.findall(text)
+            if _dims(dims)[-2:] in ((MD, MF), (MF, MD))
+            and op not in ("parameter", "get-tuple-element", "tuple",
+                           "bitcast", "while")]
+    assert not held, held
