@@ -71,9 +71,10 @@ class LogitTap(InferenceSession):
         super().__init__(backend)
         self.rows = {}
 
-    def _select_token(self, out, req, key):
-        self.rows.setdefault(req.request_id, []).append(out.logits)
-        return super()._select_token(out, req, key)
+    def _select_token(self, a, out):
+        # a greedy row's logits stay on the device until ``logits()``
+        self.rows.setdefault(a.req.request_id, []).append(out.logits)
+        return super()._select_token(a, out)
 
     def logits(self, request_id: str) -> np.ndarray:
         return np.stack([np.asarray(r, np.float32).reshape(-1)

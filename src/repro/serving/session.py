@@ -127,10 +127,10 @@ class _Active:
     """In-flight request state (one slot's worth of work)."""
     req: ServeRequest
     state: Dict[str, Any]
-    rng: jax.Array
     t0: float
     ttft_s: float = 0.0
     queue_wait_s: float = 0.0
+    rng: Optional[jax.Array] = None          # sampler key, made at 1st draw
     tokens: List[np.ndarray] = dataclasses.field(default_factory=list)
     stopped: Optional[np.ndarray] = None     # (B,) bool: row hit a stop token
     last_tok: Optional[np.ndarray] = None    # (B, 1) int32
@@ -144,6 +144,42 @@ class _Active:
                 or (self.stopped is not None and bool(self.stopped.all())))
 
 
+class _LazyLogits:
+    """``logits[index]`` of a batched step, sliced only if something reads
+    it (``np.asarray``).  A greedy device-argmax token never does, so
+    retiring it makes no device call, while a session that observes each
+    row's logits still gets them; ``read`` says whether one did."""
+    __slots__ = ("_logits", "_index", "read")
+
+    def __init__(self, logits: jax.Array, index: Any) -> None:
+        self._logits = logits
+        self._index = index
+        self.read = False
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        self.read = True
+        return np.asarray(self._logits[self._index], dtype)
+
+
+def _token_from_logits(req: ServeRequest, next_token: Any) -> bool:
+    """Whether ``req``'s token is chosen from the logits (a sampler draws,
+    ``readback == "logits"``, or no device argmax) rather than taken from
+    the step's ``next_token``.  Only such a row needs its logits sliced
+    and, if it samples, a key."""
+    return (next_token is None or req.sampler.kind != "greedy"
+            or req.readback == "logits")
+
+
+def _row_output(a: _Active, logits: jax.Array, index: Any,
+                nxt: Optional[np.ndarray]) -> StepOutput:
+    """Request ``a``'s row of a batched step, ``nxt`` its next-token row
+    already on the host.  The logits are sliced on the device only when
+    the token is chosen from them; otherwise they stay lazy."""
+    if _token_from_logits(a.req, nxt):
+        return StepOutput(logits[index], nxt)
+    return StepOutput(_LazyLogits(logits, index), nxt)
+
+
 class InferenceSession:
     """Serve requests through one compiled ``ExecutionBackend``."""
 
@@ -151,21 +187,34 @@ class InferenceSession:
         self.backend = backend
 
     # ------------------------------------------------------------------
-    def _select_token(self, out: StepOutput, req: ServeRequest,
-                      key: jax.Array) -> np.ndarray:
+    @staticmethod
+    def _draw_key(a: _Active) -> jax.Array:
+        """The next key of ``a``'s sampler: ``PRNGKey(seed)`` is made at
+        the first draw and split once per drawn token, so a stochastic
+        request (which draws every token) sees the same keys as if every
+        token split one; a greedy request never touches the RNG."""
+        if a.rng is None:
+            a.rng = jax.random.PRNGKey(a.req.seed)
+        a.rng, key = jax.random.split(a.rng)
+        return key
+
+    def _select_token(self, a: _Active, out: StepOutput) -> np.ndarray:
         """StepOutput → host (B, 1) int32, honoring sampler + readback."""
+        req = a.req
+        if not _token_from_logits(req, out.next_token):
+            # device-side argmax: one int32 per row crosses the bus
+            return np.asarray(out.next_token, np.int32).reshape(-1, 1)
         greedy = req.sampler.kind == "greedy"
         if req.readback == "logits":
             # App. H full-readback baseline: whole vocab row crosses the bus
             logits = np.asarray(out.logits)
             if greedy:
                 return np.argmax(logits, -1).astype(np.int32).reshape(-1, 1)
-            tok = sample(jax.numpy.asarray(logits), req.sampler, key)
+            tok = sample(jax.numpy.asarray(logits), req.sampler,
+                         self._draw_key(a))
             return np.asarray(tok, np.int32).reshape(-1, 1)
-        if greedy and out.next_token is not None:
-            # device-side argmax: one int32 per row crosses the bus
-            return np.asarray(out.next_token, np.int32).reshape(-1, 1)
-        tok = sample(out.logits, req.sampler, key)
+        tok = sample(out.logits, req.sampler,
+                     None if greedy else self._draw_key(a))
         return np.asarray(tok, np.int32).reshape(-1, 1)
 
     def _emit(self, a: _Active, tok: np.ndarray) -> None:
@@ -183,13 +232,11 @@ class InferenceSession:
         """Open a request WITHOUT running prefill — stamps t0 only.  The
         chunked-prefill scheduler spreads the prompt over many cycles, so
         TTFT starts at admission, not at the (much later) final chunk."""
-        return _Active(req=req, state=None, rng=jax.random.PRNGKey(req.seed),
-                       t0=time.perf_counter())
+        return _Active(req=req, state=None, t0=time.perf_counter())
 
     def first(self, a: _Active, out: StepOutput) -> None:
         """Consume the prefill output: sample + emit the first token."""
-        a.rng, key = jax.random.split(a.rng)
-        tok = self._select_token(out, a.req, key)
+        tok = self._select_token(a, out)
         a.ttft_s = time.perf_counter() - a.t0
         self._emit(a, tok)
 
@@ -212,9 +259,10 @@ class InferenceSession:
         """Consume one ALREADY-COMPUTED decode output for this request —
         the continuous scheduler computes a whole cycle's outputs in one
         batched dispatch and feeds each request its own row here.  Sampler
-        RNG, streaming, and stop handling are identical to ``step``."""
-        a.rng, key = jax.random.split(a.rng)
-        self._emit(a, self._select_token(out, a.req, key))
+        RNG, streaming, and stop handling are identical to ``step``.  A
+        greedy device-argmax row's ``logits`` are sliced only if read
+        (``np.asarray``): its token needs ``next_token`` alone."""
+        self._emit(a, self._select_token(a, out))
         return a.done
 
     def finish(self, a: _Active) -> ServeResult:
@@ -245,7 +293,7 @@ class InferenceSession:
             n_rest = req.max_new_tokens - 1
             rest = np.asarray(self.backend.generate_ondevice(
                 a.state, a.last_tok, n_rest, req.sampler,
-                jax.random.split(a.rng)[1]), np.int32)  # ONE readback
+                self._draw_key(a)), np.int32)  # ONE readback
             for i in range(n_rest):
                 a.tokens.append(rest[:, i:i + 1])
             return self.finish(a)
@@ -951,19 +999,27 @@ class Scheduler:
             st.sync_readback_s += dt
         if routed is not None:
             self._count_routed(np.asarray(routed)[:, list(slots)])
+        host_only = 0
         with tr.span("sample_emit", track="scheduler", slots=len(slots)):
             for s in slots:
                 a = active[s]
-                row = StepOutput(out.logits[s:s + 1],
-                                 None if nxt is None else nxt[s:s + 1])
+                row = _row_output(a, out.logits, np.s_[s:s + 1],
+                                  None if nxt is None else nxt[s:s + 1])
                 st.tokens += 1
-                if self.session.step_row(a, row):
+                done = self.session.step_row(a, row)
+                host_only += (isinstance(row.logits, _LazyLogits)
+                              and not row.logits.read)
+                if done:
                     results[a.req.request_id] = self.session.finish(a)
                     bstate = backend.release_slot(bstate, s,
                                                   tokens=self._realized(a))
                     tr.instant("release", track=f"slot{s}",
                                req=a.req.request_id, n_new=len(a.tokens))
                     del active[s]
+        # this cycle's rows handed out lazily and left unread while they
+        # were retired: tokens retired with no device call (a key split or
+        # a logits slice).  Single-step decode cycles only.
+        tr.counter("retire.host_only", host_only, track="scheduler")
         return bstate
 
     def _count_routed(self, ids: np.ndarray) -> None:
@@ -1273,7 +1329,8 @@ class Scheduler:
                 st.spec_tokens += 1
                 pg.pos[s] += 1
                 done = self.session.step_row(
-                    a, StepOutput(out.logits[s:s + 1, 0:1], nxt[s:s + 1, 0:1]))
+                    a, _row_output(a, out.logits, np.s_[s:s + 1, 0:1],
+                                   nxt[s:s + 1, 0:1]))
             else:
                 accepted = greedy_accept(d, nxt[s])
                 emitted = 0
@@ -1285,8 +1342,8 @@ class Scheduler:
                     st.spec_tokens += 1
                     emitted += 1
                     done = self.session.step_row(
-                        a, StepOutput(out.logits[s:s + 1, j:j + 1],
-                                      nxt[s:s + 1, j:j + 1]))
+                        a, _row_output(a, out.logits, np.s_[s:s + 1, j:j + 1],
+                                       nxt[s:s + 1, j:j + 1]))
                     if done:
                         break
                 st.draft_tokens_proposed += int(d.size)

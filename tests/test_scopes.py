@@ -11,8 +11,8 @@ from repro.configs import get_smoke_config
 from repro.models import build_model
 from repro.obs import Tracer
 from repro.obs.scopes import SCOPES, _parse, op_scopes, scope_of
-from repro.serving import (InferenceSession, Scheduler, ServeRequest,
-                           create_backend)
+from repro.serving import (InferenceSession, SamplerConfig, Scheduler,
+                           ServeRequest, create_backend)
 
 MOE_SCOPES = ("moe_route", "moe_experts")
 
@@ -201,3 +201,49 @@ def test_moe_ids_cross_only_for_a_live_tracer(monkeypatch):
                               .reshape(1, -1), max_new_tokens=4,
                               request_id="r"))
     assert sched.run()["r"].n_new == 4
+
+
+# -- the scheduler's host-only retire counter --------------------------------
+
+class _Observing(InferenceSession):
+    """Reads every row's logits while it is retired."""
+
+    def step_row(self, a, out):
+        np.asarray(out.logits)
+        return super().step_row(a, out)
+
+
+@pytest.mark.parametrize("kind", ["greedy", "temperature", "greedy-observed"])
+def test_retire_counter_counts_tokens_retired_on_the_host(kind):
+    """``retire.host_only`` is each cycle's count of tokens retired with
+    host numpy alone: every row of a greedy cycle, so the cycle's
+    occupancy, and none of a sampled one, whose rows need a key and
+    their logits, nor of a greedy one whose session reads the logits."""
+    cfg = get_smoke_config("qwen2-1.5b", layers=2)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    backend = create_backend("model", model, params, batch=1, max_len=64)
+    tracer = Tracer()
+    session = (_Observing if kind == "greedy-observed"
+               else InferenceSession)(backend)
+    sched = Scheduler(session, num_slots=2,
+                      kv_layout="paged", prefill_chunk=8, block_size=8,
+                      tracer=tracer)
+    rng = np.random.default_rng(6)
+    for n_new in (6, 9, 4):
+        p = rng.integers(0, cfg.vocab_size, (1, 10)).astype(np.int32)
+        sched.submit(ServeRequest(prompt=p, max_new_tokens=n_new,
+                                  sampler=SamplerConfig(kind.split("-")[0])))
+    sched.run()
+    events = tracer.events()
+    host_only = [e.args["value"] for e in events
+                 if e.name == "retire.host_only"]
+    cycles = sorted((e for e in events if e.name == "decode_cycle"),
+                    key=lambda e: e.args["cycle"])
+    occupancy = [e.args["occupancy"] for e in cycles]
+    assert len(cycles) == sched.last_stats.cycles and 2 in occupancy
+    if kind == "greedy":
+        assert host_only == occupancy
+        assert sched.last_stats.overlap_cycles > 0
+    else:
+        assert host_only == [0] * len(occupancy)
