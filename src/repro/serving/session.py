@@ -566,10 +566,10 @@ class SchedulerStats:
 class SchedulerConfig:
     """Every :class:`Scheduler` policy knob in ONE validated dataclass.
 
-    The scheduler's constructor accreted a kwarg per feature PR; this is
-    the consolidated surface.  Build one and pass
-    ``Scheduler(session, config=cfg)`` — or keep calling with the
-    individual kwargs, which now merely populate a config for you.
+    Pass one as ``Scheduler(session, config=cfg)``, or give the same
+    fields as keyword arguments (``Scheduler(session, 4,
+    kv_layout="paged")``), which fill one for you; the scheduler reads
+    its policy from ``scheduler.config`` either way.
 
     Fields:
       num_slots: concurrent request slots — the batch width decode
@@ -666,15 +666,18 @@ class Scheduler:
     """Multi-request slot scheduler with continuous batching.
 
     Requests queue FIFO; up to ``num_slots`` run concurrently.  In the
-    default **continuous** mode every cycle issues ONE batched decode
-    across all active slots (``backend.decode_batch`` over a slot-major
-    KV pool with per-slot positions), so per-cycle dispatch overhead —
-    the paper's ~95 µs/op batch-1 wall — is amortized over occupancy.
-    Admission is in-flight: whenever a slot frees, the next queued request
-    prefills into it between cycles, with no drain barrier; stop
-    conditions terminate each slot independently; FIFO admission plus the
-    per-request ``queue_wait_s`` recorded in ``last_stats`` give the
-    fairness accounting.
+    default **continuous** mode every run goes through ONE batched loop,
+    whichever the KV layout: each cycle issues ONE batched decode across
+    all active slots (``backend.decode_batch`` with per-slot positions),
+    so per-cycle dispatch overhead — the paper's ~95 µs/op batch-1 wall —
+    is amortized over occupancy.  Admission is in-flight: whenever a slot
+    frees, the next queued request is admitted between cycles, with no
+    drain barrier; stop conditions terminate each slot independently;
+    FIFO admission plus the per-request ``queue_wait_s`` recorded in
+    ``last_stats`` give the fairness accounting.  Every kind of cycle
+    (single-step, multi-step, speculative) shares one issue
+    (``_issue``), one read-back (``_readback``) and one release
+    (``_finish_slot``).
 
     ``continuous=False`` keeps the pre-batching behavior — one
     ``decode_step`` dispatch per active slot per cycle — as the
@@ -682,11 +685,14 @@ class Scheduler:
     Backends that cannot batch (``capabilities.decode_batch`` False) run
     the same per-slot loop through the uniform fallback contract.
 
-    ``kv_layout="paged"`` swaps the dense slot-major pool for the paged
-    block-pool subsystem (``repro.serving.paging``): admission is a radix
-    prefix-cache match (a warm hit skips prefill dispatches for the whole
-    shared span), blocks are claimed lazily as sequences grow, and prefill
-    is **chunked** — ``prefill_chunk`` prompt tokens per cycle interleaved
+    The KV layout matters in two places only: the batch state
+    (``_batch_state``) and admission (``_admit``).  ``kv_layout="dense"``
+    prefills a whole prompt at admission into a slot-major pool.
+    ``kv_layout="paged"`` uses the block-pool subsystem
+    (``repro.serving.paging``): admission is a radix prefix-cache match
+    (a warm hit skips prefill dispatches for the whole shared span),
+    blocks are claimed lazily as sequences grow, and prefill is
+    **chunked** — ``prefill_chunk`` prompt tokens per cycle interleaved
     with decode, so one long admission no longer stalls every active slot.
     The paged batch state (block pool + radix cache) persists across
     ``run`` calls, so prefix hits accumulate over a scheduler's lifetime.
@@ -750,15 +756,13 @@ class Scheduler:
         """Args:
           session: the :class:`InferenceSession` whose backend executes
             every dispatch; the scheduler only orchestrates.
-          config: a :class:`SchedulerConfig` carrying every policy knob —
-            the ONE configuration surface (see its docstring for the
-            per-field semantics).
-          num_slots / **kwargs: DEPRECATED per-field construction
-            (``Scheduler(session, 4, kv_layout="paged", ...)``).  The
-            kwargs simply populate a ``SchedulerConfig`` — same fields,
-            same validation, same error messages — and cannot be mixed
-            with ``config=``.  Prefer passing a config; the kwargs path
-            remains for the historical call sites.
+          config: a :class:`SchedulerConfig` carrying every policy knob
+            (see its docstring for the per-field semantics).
+          num_slots / **kwargs: the same fields given one by one
+            (``Scheduler(session, 4, kv_layout="paged", ...)``); they fill
+            a ``SchedulerConfig`` — same validation, same error messages —
+            and cannot be mixed with ``config=``.  Either way the policy
+            is read from ``self.config``.
         """
         if config is not None:
             if num_slots is not None or kwargs:
@@ -772,16 +776,6 @@ class Scheduler:
         self._spec: Optional[SpeculativeConfig] = config.speculative
         self._drafter: Optional[Drafter] = None
         self.session = session
-        self.num_slots = config.num_slots
-        self.continuous = config.continuous
-        self.kv_layout = config.kv_layout
-        self.prefill_chunk = config.prefill_chunk
-        self.prefix_cache = config.prefix_cache
-        self.block_size = config.block_size
-        self.num_blocks = config.num_blocks
-        self.async_readback = config.async_readback
-        self.preemption = config.preemption
-        self.decode_horizon = config.decode_horizon
         self._queue: List[ServeRequest] = []
         self._future: List[Tuple[float, int, ServeRequest]] = []  # heap
         self._preempted: List[Dict[str, Any]] = []   # evicted, awaiting slot
@@ -861,20 +855,17 @@ class Scheduler:
     def run(self) -> Dict[str, ServeResult]:
         """Drain the queue; returns {request_id: ServeResult}.  Amortization
         and fairness accounting for the run lands in ``self.last_stats``."""
-        st = SchedulerStats(num_slots=self.num_slots,
-                            continuous=self.continuous,
-                            kv_layout=self.kv_layout,
-                            decode_horizon=self.decode_horizon,
+        cfg = self.config
+        st = SchedulerStats(num_slots=cfg.num_slots,
+                            continuous=cfg.continuous,
+                            kv_layout=cfg.kv_layout,
+                            decode_horizon=cfg.decode_horizon,
                             speculative=self._drafter_name())
         backend = self.session.backend
         d0 = backend.dispatch_stats().dispatches
         t0 = time.perf_counter()
-        if not self.continuous:
-            results = self._run_sequential(st)
-        elif self.kv_layout == "paged":
-            results = self._run_paged(st)
-        else:
-            results = self._run_continuous(st)
+        results = (self._run_batched(st) if cfg.continuous
+                   else self._run_sequential(st))
         st.wall_s = time.perf_counter() - t0
         st.dispatches = backend.dispatch_stats().dispatches - d0
         st.completed = len(results)
@@ -948,18 +939,31 @@ class Scheduler:
         return self.tracer.span("decode_cycle", track="scheduler",
                                 cycle=st.cycles, occupancy=occupancy, **args)
 
-    def _issue_cycle(self, bstate, active: Dict[int, "_Active"],
-                     st: SchedulerStats, tokens):
-        """ONE batched decode dispatch for every active slot."""
-        slots = tuple(sorted(active))
-        bstate, out = self.session.backend.decode_batch(bstate, tokens, slots)
+    def _issue(self, bstate, slots: Tuple[int, ...], tokens,
+               st: SchedulerStats, *, horizon: int = 1, stop_table=None,
+               spans=None, overlapped: bool = False):
+        """ONE host submission for ``slots`` — every kind of cycle goes
+        through here: ``decode_batch`` at horizon 1, ``decode_multi``
+        above it, ``verify_paged`` when ``spans`` are given — and its
+        cycle bookkeeping.  The backend method is looked up at every call,
+        so a wrapper put on the backend instance sees every cycle."""
+        backend = self.session.backend
+        if spans is not None:
+            bstate, out = backend.verify_paged(bstate, tokens, slots, spans)
+        elif horizon == 1:
+            bstate, out = backend.decode_batch(bstate, tokens, slots)
+        else:
+            bstate, out = backend.decode_multi(
+                bstate, tokens, slots, horizon=horizon, stop_table=stop_table)
         st.cycles += 1
         st.occupancy_sum += len(slots)
+        st.overlap_cycles += overlapped
+        st.multi_cycles += horizon > 1
         self._track_kv(bstate, st)
-        return bstate, slots, out
+        return bstate, out
 
     def _host_tokens(self, active: Dict[int, "_Active"]) -> np.ndarray:
-        tokens = np.zeros((self.num_slots, 1), np.int32)
+        tokens = np.zeros((self.config.num_slots, 1), np.int32)
         for s, a in active.items():
             tokens[s, 0] = a.last_tok[0, 0]
         return tokens
@@ -974,30 +978,57 @@ class Scheduler:
                else np.zeros((0,), np.int32))
         return np.concatenate([prompt, gen])
 
-    def _retire_cycle(self, out: StepOutput, slots, active, results, bstate,
-                      st: SchedulerStats, *, overlapped: bool):
-        """Read a cycle's tokens back and feed each slot its row."""
-        backend = self.session.backend
-        tr = self.tracer
-        with tr.span("readback", track="scheduler", overlapped=overlapped):
+    def _readback(self, st: SchedulerStats, overlapped: bool, values,
+                  **span):
+        """Bring a retiring cycle's ``values`` to the host, timed into
+        ``st.overlap_readback_s`` (the next cycle is already issued) or
+        ``st.sync_readback_s``.  A tuple of arrays crosses in ONE
+        ``jax.device_get``; a single array takes ``np.asarray``, which
+        skips the pytree walk (``jax.device_get`` of one (1, 1) array
+        cost about 20 µs more on an XLA:CPU host)."""
+        with self.tracer.span("readback", track="scheduler",
+                              overlapped=overlapped, **span):
             t0 = time.perf_counter()
-            # one host readback per CYCLE (not per slot) in the greedy
-            # token-readback regime: a (num_slots,) int32 vector; a live
-            # tracer takes an MoE step's expert ids in the same transfer
-            routed = None
-            if tr.enabled and out.routed is not None \
-                    and out.next_token is not None:
-                nxt, routed = jax.device_get((out.next_token, out.routed))
-                nxt = np.asarray(nxt, np.int32)
-            else:
-                nxt = (np.asarray(out.next_token, np.int32)
-                       if out.next_token is not None else None)
+            if isinstance(values, tuple):
+                values = jax.device_get(values)
+            elif values is not None:
+                values = np.asarray(values)
             dt = time.perf_counter() - t0
         if overlapped:
             st.overlap_readback_s += dt
         else:
             st.sync_readback_s += dt
-        if routed is not None:
+        return values
+
+    def _finish_slot(self, bstate, s: int, active: Dict[int, "_Active"],
+                     results: Dict[str, ServeResult]):
+        """Finish the request in slot ``s``: its result, the slot's
+        release with the realized tokens (a paged backend radix-caches
+        them), the ``release`` instant and the drafter's slot state."""
+        a = active.pop(s)
+        results[a.req.request_id] = self.session.finish(a)
+        bstate = self.session.backend.release_slot(bstate, s,
+                                                   tokens=self._realized(a))
+        self.tracer.instant("release", track=f"slot{s}",
+                            req=a.req.request_id, n_new=len(a.tokens))
+        if self._drafter is not None:
+            self._drafter.release(s)
+        return bstate
+
+    def _retire_cycle(self, out: StepOutput, slots, active, results, bstate,
+                      st: SchedulerStats, *, overlapped: bool):
+        """Read a cycle's tokens back and feed each slot its row."""
+        tr = self.tracer
+        # one host readback per CYCLE (not per slot) in the greedy
+        # token-readback regime: a (num_slots,) int32 vector; a live
+        # tracer takes an MoE step's expert ids in the same transfer
+        routed = (out.routed if tr.enabled and out.next_token is not None
+                  else None)
+        if routed is None:
+            nxt = self._readback(st, overlapped, out.next_token)
+        else:
+            nxt, routed = self._readback(st, overlapped,
+                                         (out.next_token, routed))
             self._count_routed(np.asarray(routed)[:, list(slots)])
         host_only = 0
         with tr.span("sample_emit", track="scheduler", slots=len(slots)):
@@ -1010,12 +1041,7 @@ class Scheduler:
                 host_only += (isinstance(row.logits, _LazyLogits)
                               and not row.logits.read)
                 if done:
-                    results[a.req.request_id] = self.session.finish(a)
-                    bstate = backend.release_slot(bstate, s,
-                                                  tokens=self._realized(a))
-                    tr.instant("release", track=f"slot{s}",
-                               req=a.req.request_id, n_new=len(a.tokens))
-                    del active[s]
+                    bstate = self._finish_slot(bstate, s, active, results)
         # this cycle's rows handed out lazily and left unread while they
         # were retired: tokens retired with no device call (a key split or
         # a logits slice).  Single-step decode cycles only.
@@ -1045,52 +1071,59 @@ class Scheduler:
                    and not a.req.stop_tokens
                    and a.req.stream is None for a in active.values())
 
-    def _drain_async(self, bstate, out: StepOutput, slots, active, results,
-                     st: SchedulerStats):
-        """Double-buffered steady state: issue cycle N+1 from cycle N's
-        on-device tokens, THEN read cycle N back — the device computes
-        while the host fetches and books the previous tokens.  Exits (and
-        sync-retires the in-flight cycle) as soon as a slot is about to
-        finish, so every issued cycle's token is emitted — no speculative
-        work is ever discarded."""
-        backend = self.session.backend
-        while (self.async_readback and out.next_token is not None
+    def _drain(self, bstate, out, slots, active, results,
+               st: SchedulerStats, horizon: int):
+        """Double-buffered steady state: issue cycle N+1 from the last
+        on-device token column of cycle N, THEN read N back — the device
+        computes while the host fetches and books the previous tokens.  A
+        cycle is one decode step at horizon 1 and a super-step of
+        ``horizon`` steps above it.  Leaves (and sync-retires the
+        in-flight cycle) as soon as a slot could finish within the next
+        two cycles, so every issued cycle's tokens are emitted — no
+        speculative work is ever discarded.  Stop tokens keep it
+        synchronous (``_async_safe``): a row ending mid-horizon would make
+        the last column the wrong next input."""
+        if horizon == 1:
+            retire, args = self._retire_cycle, {}
+        else:
+            retire, args = self._retire_multi, {"horizon": horizon,
+                                                "multi": True}
+        while (self.config.async_readback
                and not self._future       # open-loop arrivals poll per cycle
+               and (horizon > 1 or out.next_token is not None)
                and self._async_safe(active)
-               and all(len(active[s].tokens) + 1
-                       < active[s].req.max_new_tokens for s in slots)):
-            with self._cycle_span(st, len(slots), overlapped=True):
-                bstate, out_next = backend.decode_batch(bstate,
-                                                        out.next_token, slots)
-                st.cycles += 1
-                st.occupancy_sum += len(slots)
-                st.overlap_cycles += 1
-                self._track_kv(bstate, st)
-                bstate = self._retire_cycle(out, slots, active, results,
-                                            bstate, st, overlapped=True)
+               and all(len(active[s].tokens) + 2 * horizon
+                       <= active[s].req.max_new_tokens for s in slots)):
+            tokens = out.next_token if horizon == 1 else out.tokens[:, -1:]
+            with self._cycle_span(st, len(slots), overlapped=True, **args):
+                bstate, out_next = self._issue(bstate, slots, tokens, st,
+                                               horizon=horizon,
+                                               overlapped=True)
+                bstate = retire(out, slots, active, results, bstate, st,
+                                overlapped=True)
             out = out_next
-        return self._retire_cycle(out, slots, active, results, bstate, st,
-                                  overlapped=False)
+        return retire(out, slots, active, results, bstate, st,
+                      overlapped=False)
 
     # -- multi-step decode capture (decode_horizon > 1) ------------------
-    def _multi_ok(self, active: Dict[int, "_Active"]) -> bool:
-        """Multi-step eligibility: the super-step samples on device, so
-        every active slot must be greedy token-readback with no stream
-        callback.  Stop tokens ARE allowed — the on-device stop table plus
-        retire-time reconciliation handle them."""
-        return (self.decode_horizon > 1
-                and self.session.backend.capabilities.decode_multi
-                and all(a.req.sampler.kind == "greedy"
-                        and a.req.readback == "token"
-                        and a.req.stream is None
-                        for a in active.values()))
-
-    def _multi_horizon(self, active: Dict[int, "_Active"]) -> int:
-        """Clip the configured horizon to the tightest remaining token
-        budget so no slot can overrun ``max_new_tokens`` mid-capture."""
-        rem = min(a.req.max_new_tokens - len(a.tokens)
-                  for a in active.values())
-        return min(self.decode_horizon, rem)
+    def _horizon(self, active: Dict[int, "_Active"]) -> int:
+        """Decode steps the next cycle covers.  Above 1 only when the
+        backend captures multi-step decode and every active slot is greedy
+        token-readback with no stream callback (the super-step samples on
+        device; stop tokens ARE allowed — the on-device stop table plus
+        retire-time reconciliation handle them).  Clipped to the tightest
+        remaining token budget so no slot overruns ``max_new_tokens``
+        mid-capture."""
+        if (self.config.decode_horizon == 1
+                or not self.session.backend.capabilities.decode_multi
+                or not all(a.req.sampler.kind == "greedy"
+                           and a.req.readback == "token"
+                           and a.req.stream is None
+                           for a in active.values())):
+            return 1
+        return min(self.config.decode_horizon,
+                   *(a.req.max_new_tokens - len(a.tokens)
+                     for a in active.values()))
 
     def _stop_table(self, active: Dict[int, "_Active"]
                     ) -> Optional[np.ndarray]:
@@ -1101,27 +1134,11 @@ class Scheduler:
                     default=0)
         if width == 0:
             return None
-        tbl = np.full((self.num_slots, width), -1, np.int32)
+        tbl = np.full((self.config.num_slots, width), -1, np.int32)
         for s, a in active.items():
             if a.req.stop_tokens:
                 tbl[s, :len(a.req.stop_tokens)] = a.req.stop_tokens
         return tbl
-
-    def _issue_multi(self, bstate, active: Dict[int, "_Active"],
-                     st: SchedulerStats, tokens, horizon: int, stop_table,
-                     *, overlapped: bool = False):
-        """ONE host submission advancing every active slot ``horizon``
-        decode cycles (``backend.decode_multi``)."""
-        slots = tuple(sorted(active))
-        bstate, out = self.session.backend.decode_multi(
-            bstate, tokens, slots, horizon=horizon, stop_table=stop_table)
-        st.cycles += 1
-        st.multi_cycles += 1
-        st.occupancy_sum += len(slots)
-        if overlapped:
-            st.overlap_cycles += 1
-        self._track_kv(bstate, st)
-        return bstate, slots, out
 
     def _retire_multi(self, out, slots, active, results, bstate,
                       st: SchedulerStats, *, overlapped: bool):
@@ -1134,21 +1151,11 @@ class Scheduler:
         before feeding the final token back, so only ``len(seq) - 1``
         positions are guaranteed-valid KV (exactly the single-step radix
         insert rule)."""
-        backend = self.session.backend
-        tr = self.tracer
-        with tr.span("readback", track="scheduler", overlapped=overlapped,
-                     multi=True):
-            t0 = time.perf_counter()
-            toks = np.asarray(out.tokens, np.int32)   # ONE readback per N
-            valid = np.asarray(out.valid, bool)
-            dt = time.perf_counter() - t0
-        if overlapped:
-            st.overlap_readback_s += dt
-        else:
-            st.sync_readback_s += dt
+        toks, valid = self._readback(st, overlapped, (out.tokens, out.valid),
+                                     multi=True)   # ONE readback per N
         horizon = toks.shape[1]
-        with tr.span("sample_emit", track="scheduler", slots=len(slots),
-                     horizon=horizon):
+        with self.tracer.span("sample_emit", track="scheduler",
+                              slots=len(slots), horizon=horizon):
             for s in slots:
                 a = active[s]
                 done = False
@@ -1162,86 +1169,10 @@ class Scheduler:
                     if done:
                         break
                 if done:
-                    seq = self._realized(a)
                     if "paged" in bstate:
-                        bstate["paged"].pos[s] = len(seq) - 1
-                    results[a.req.request_id] = self.session.finish(a)
-                    bstate = backend.release_slot(bstate, s, tokens=seq)
-                    tr.instant("release", track=f"slot{s}",
-                               req=a.req.request_id, n_new=len(a.tokens))
-                    del active[s]
+                        bstate["paged"].pos[s] = len(self._realized(a)) - 1
+                    bstate = self._finish_slot(bstate, s, active, results)
         return bstate
-
-    def _drain_multi(self, bstate, out, slots, active, results,
-                     st: SchedulerStats, horizon: int):
-        """Double-buffered super-steps: issue super-step N+1 from the last
-        on-device token column of super-step N, THEN retire N overlapped —
-        the multi-step analogue of ``_drain_async``.  Requires the
-        stop-free steady state (``_async_safe``): with stop tokens a row
-        may end mid-horizon, making the last column the wrong next
-        input."""
-        while (self.async_readback
-               and not self._future      # open-loop arrivals poll per step
-               and self._async_safe(active)
-               and all(len(active[s].tokens) + 2 * horizon
-                       <= active[s].req.max_new_tokens for s in slots)):
-            with self._cycle_span(st, len(slots), horizon=horizon,
-                                  multi=True, overlapped=True):
-                bstate, _, out_next = self._issue_multi(
-                    bstate, active, st, out.tokens[:, -1:], horizon, None,
-                    overlapped=True)
-                bstate = self._retire_multi(out, slots, active, results,
-                                            bstate, st, overlapped=True)
-            out = out_next
-        return self._retire_multi(out, slots, active, results, bstate, st,
-                                  overlapped=False)
-
-    # -- continuous batching (the production path) ----------------------
-    def _run_continuous(self, st: SchedulerStats) -> Dict[str, ServeResult]:
-        backend = self.session.backend
-        if self._bstate is None:
-            self._bstate = backend.alloc_slots(self.num_slots)
-        bstate = self._bstate
-        results: Dict[str, ServeResult] = {}
-        active: Dict[int, _Active] = {}
-        while self._queue or self._future or active:
-            self._drain_arrivals()
-            self._wait_for_arrival(busy=bool(active))
-            # in-flight admission: prefill queued requests into free slots
-            # between decode cycles — running slots never drain or stall
-            while self._queue and len(active) < self.num_slots:
-                req = self._pop_next()
-                self._check_row(req)
-                with self.tracer.span("admit", track="scheduler",
-                                      req=req.request_id):
-                    a = self._start(req, st)
-                if a.done:
-                    results[a.req.request_id] = self.session.finish(a)
-                    continue
-                slot = min(s for s in range(self.num_slots)
-                           if s not in active)
-                bstate = backend.admit_slot(bstate, slot, a.state)
-                a.state = None               # KV now lives in the slot pool
-                active[slot] = a
-            if not active:
-                continue
-            horizon = self._multi_horizon(active)
-            if horizon > 1 and self._multi_ok(active):
-                with self._cycle_span(st, len(active), horizon=horizon,
-                                      multi=True):
-                    bstate, slots, out = self._issue_multi(
-                        bstate, active, st, self._host_tokens(active),
-                        horizon, self._stop_table(active))
-                bstate = self._drain_multi(bstate, out, slots, active,
-                                           results, st, horizon)
-                continue
-            with self._cycle_span(st, len(active)):
-                bstate, slots, out = self._issue_cycle(
-                    bstate, active, st, self._host_tokens(active))
-            bstate = self._drain_async(bstate, out, slots, active, results,
-                                       st)
-        self._bstate = bstate
-        return results
 
     # -- speculative draft/verify/commit --------------------------------
     def _drafter_name(self) -> str:
@@ -1277,13 +1208,12 @@ class Scheduler:
         by pure bookkeeping (zero KV copies: the drafted K/V sits past the
         committed position where nothing can read it).
         """
-        backend = self.session.backend
         pg = bstate["paged"]
         drafter = self._ensure_drafter()
         k = self._spec.k
         width = k + 1
         slots = tuple(sorted(active))
-        tokens = np.zeros((self.num_slots, width), np.int32)
+        tokens = np.zeros((self.config.num_slots, width), np.int32)
         spans, drafts, forks = [], {}, {}
         disp0 = drafter.dispatches
         tr = self.tracer
@@ -1309,16 +1239,10 @@ class Scheduler:
         st.draft_dispatches += drafter.dispatches - disp0
         with tr.span("verify", track="scheduler", occupancy=len(slots),
                      cycle=st.cycles):
-            bstate, out = backend.verify_paged(bstate, tokens, slots, spans)
-        st.cycles += 1
+            bstate, out = self._issue(bstate, slots, tokens, st, spans=spans)
         st.spec_cycles += 1
         st.verify_dispatches += 1
-        st.occupancy_sum += len(slots)
-        self._track_kv(bstate, st)
-        with tr.span("readback", track="scheduler", overlapped=False):
-            t0 = time.perf_counter()
-            nxt = np.asarray(out.next_token, np.int32)   # (S, width)
-            st.sync_readback_s += time.perf_counter() - t0
+        nxt = self._readback(st, False, out.next_token)   # (S, width)
         for s in slots:
             a = active[s]
             d = drafts.get(s)
@@ -1357,11 +1281,7 @@ class Scheduler:
                            proposed=int(d.size), accepted=accepted,
                            emitted=emitted)
             if done:
-                results[a.req.request_id] = self.session.finish(a)
-                bstate = backend.release_slot(bstate, s,
-                                              tokens=self._realized(a))
-                drafter.release(s)
-                del active[s]
+                bstate = self._finish_slot(bstate, s, active, results)
         return bstate
 
     # -- SLO-aware preemption (oversubscription survival) ----------------
@@ -1383,14 +1303,14 @@ class Scheduler:
         it is the choice that produces the missing measurement.
         """
         can_swap = self.session.backend.capabilities.preemption
-        if self.preemption == "swap":
+        if self.config.preemption == "swap":
             if not can_swap:
                 raise ValueError(
                     f"backend {self.session.backend.capabilities.name!r} "
                     "cannot swap block chains (capabilities.preemption is "
                     "False); use preemption='recompute' or 'auto'")
             return "swap"
-        if self.preemption == "recompute" or not can_swap:
+        if self.config.preemption == "recompute" or not can_swap:
             return "recompute"
         up, pf = self._ewma_upload_s_per_block, self._ewma_prefill_s_per_tok
         if up is None or pf is None:
@@ -1411,7 +1331,8 @@ class Scheduler:
         Mid-prefill slots are never victims — their KV is cheapest to
         finish, not to throw away."""
         backend = self.session.backend
-        while active and len(active) + len(prefilling) >= self.num_slots:
+        while (active
+               and len(active) + len(prefilling) >= self.config.num_slots):
             waiting = [r.priority for r in self._queue] \
                 + [rec["a"].req.priority for rec in self._preempted]
             if not waiting:
@@ -1482,19 +1403,23 @@ class Scheduler:
                 st.swap_upload_dispatches += uploads
                 active[slot] = a
             else:
-                realized = self._realized(a)
-                info = backend.admit_paged(bstate, slot, realized[:-1])
-                if info.cached:
-                    st.prefix_hits += 1
-                    st.prefix_hit_tokens += info.cached
-                st.prompt_tokens += info.total
+                self._bind_paged(bstate, slot, self._realized(a)[:-1], st)
                 a.resuming = True
                 prefilling[slot] = a
         return bstate
 
-    # -- paged KV + radix prefix cache + chunked prefill -----------------
-    def _run_paged(self, st: SchedulerStats) -> Dict[str, ServeResult]:
-        backend = self.session.backend
+    # -- the batched loop: every continuous run, either KV layout --------
+    def _batch_state(self):
+        """The batch state, made on the first run and kept across runs (a
+        paged pool keeps its radix cache, so prefix hits accumulate over
+        the scheduler's lifetime).  With ``_admit``, the only place the KV
+        layout matters: dense is a slot-major pool; paged a block pool,
+        checked against the backend's capabilities every run."""
+        cfg, backend = self.config, self.session.backend
+        if cfg.kv_layout == "dense":
+            if self._bstate is None:
+                self._bstate = backend.alloc_slots(cfg.num_slots)
+            return self._bstate
         caps = backend.capabilities
         caps.require("paged_kv", hint="use kv_layout='dense'")
         if self._spec is not None:
@@ -1502,35 +1427,79 @@ class Scheduler:
                          hint="drop speculative= or use the model backend")
         if self._bstate is None:
             self._bstate = backend.alloc_slots_paged(
-                self.num_slots, block_size=self.block_size,
-                prefill_chunk=self.prefill_chunk,
-                num_blocks=self.num_blocks, prefix_cache=self.prefix_cache,
+                cfg.num_slots, block_size=cfg.block_size,
+                prefill_chunk=cfg.prefill_chunk, num_blocks=cfg.num_blocks,
+                prefix_cache=cfg.prefix_cache,
                 spec_slack=(self._spec.k + 1) if self._spec else 0)
-        bstate = self._bstate
-        pg = bstate["paged"]
-        radix = bstate["radix"]
         if self.tracer.enabled:
-            pg.attach_tracer(self.tracer)
-            if radix is not None:
-                radix.tracer = self.tracer
-        cow0 = pg.cow_copies
+            self._bstate["paged"].attach_tracer(self.tracer)
+            if self._bstate["radix"] is not None:
+                self._bstate["radix"].tracer = self.tracer
+        return self._bstate
+
+    def _bind_paged(self, bstate, slot: int, tokens,
+                    st: SchedulerStats) -> None:
+        """``admit_paged`` (radix match + block-table setup, no compute)
+        and its prefix-cache accounting."""
+        info = self.session.backend.admit_paged(bstate, slot, tokens)
+        if info.cached:
+            st.prefix_hits += 1
+            st.prefix_hit_tokens += info.cached
+        st.prompt_tokens += info.total
+
+    def _admit(self, bstate, slot: int, active: Dict[int, _Active],
+               prefilling: Dict[int, _Active], results,
+               st: SchedulerStats):
+        """Bind the next queued request to the free ``slot``.  Dense
+        prefills the whole prompt now and moves its state into the slot,
+        which decodes from the next cycle; a request done at its first
+        token finishes here and never takes the slot.  Paged only binds
+        the prompt: the slot prefills chunk by chunk in the loop."""
+        req = self._pop_next()
+        prompt = self._check_row(req)
+        dense = self.config.kv_layout == "dense"
+        with self.tracer.span("admit", track="scheduler",
+                              req=req.request_id, slot=slot):
+            if dense:
+                a = self._start(req, st)
+            else:
+                a = self.session.begin(req)
+                self._book_admission(a, st)
+                self._bind_paged(bstate, slot, prompt, st)
+        if not dense:
+            prefilling[slot] = a
+        elif a.done:
+            results[req.request_id] = self.session.finish(a)
+        else:
+            bstate = self.session.backend.admit_slot(bstate, slot, a.state)
+            a.state = None               # KV now lives in the slot pool
+            active[slot] = a
+        return bstate
+
+    def _run_batched(self, st: SchedulerStats) -> Dict[str, ServeResult]:
+        cfg = self.config
+        backend = self.session.backend
+        bstate = self._batch_state()
+        pg, radix = bstate.get("paged"), bstate.get("radix")
+        cow0 = pg.cow_copies if pg is not None else 0
         ev0 = radix.evictions if radix is not None else 0
         results: Dict[str, ServeResult] = {}
         active: Dict[int, _Active] = {}
-        prefilling: Dict[int, _Active] = {}
+        prefilling: Dict[int, _Active] = {}       # paged slots mid-prefill
         while (self._queue or self._future or self._preempted
                or active or prefilling):
             self._drain_arrivals()
             self._wait_for_arrival(
                 busy=bool(active or prefilling or self._preempted))
-            if self.preemption != "off":
+            if cfg.preemption != "off":
                 bstate = self._maybe_preempt(bstate, active, prefilling, st)
-            # admission: radix match + block-table setup only (no compute);
-            # preempted requests compete with fresh arrivals by priority
-            # (resume wins ties — they already waited once)
+            # in-flight admission into free slots between cycles — running
+            # slots never drain or stall; preempted requests compete with
+            # fresh arrivals by priority (resume wins ties — they already
+            # waited once)
             while ((self._queue or self._preempted)
-                   and len(active) + len(prefilling) < self.num_slots):
-                slot = min(s for s in range(self.num_slots)
+                   and len(active) + len(prefilling) < cfg.num_slots):
+                slot = min(s for s in range(cfg.num_slots)
                            if s not in active and s not in prefilling)
                 qpri = max((r.priority for r in self._queue), default=None)
                 ppri = max((rec["a"].req.priority
@@ -1538,19 +1507,9 @@ class Scheduler:
                 if ppri is not None and (qpri is None or ppri >= qpri):
                     bstate = self._resume_one(bstate, slot, active,
                                               prefilling, st)
-                    continue
-                req = self._pop_next()
-                prompt = self._check_row(req)
-                a = self.session.begin(req)
-                self._book_admission(a, st)
-                with self.tracer.span("admit", track="scheduler",
-                                      req=req.request_id, slot=slot):
-                    info = backend.admit_paged(bstate, slot, prompt)
-                if info.cached:
-                    st.prefix_hits += 1
-                    st.prefix_hit_tokens += info.cached
-                st.prompt_tokens += info.total
-                prefilling[slot] = a
+                else:
+                    bstate = self._admit(bstate, slot, active, prefilling,
+                                         results, st)
             # ONE prefill chunk per admitting slot, interleaved with the
             # decode cycle below — a long prompt admits over many cycles
             # without ever stalling the slots already decoding
@@ -1568,22 +1527,17 @@ class Scheduler:
                 st.prefill_chunks += 1
                 if out is None:
                     continue
-                a = prefilling.pop(slot)
+                a = active[slot] = prefilling.pop(slot)
                 if a.resuming:
                     # recompute-resume: KV is rebuilt, but this "first
                     # token" was emitted before the preemption — discard
                     # the logits, go straight back to decoding last_tok
                     a.resuming = False
-                    active[slot] = a
                     continue
                 self.session.first(a, out)
                 st.tokens += 1
                 if a.done:
-                    results[a.req.request_id] = self.session.finish(a)
-                    bstate = backend.release_slot(bstate, slot,
-                                                  tokens=self._realized(a))
-                else:
-                    active[slot] = a
+                    bstate = self._finish_slot(bstate, slot, active, results)
             self._track_kv(bstate, st)
             if not active:
                 continue
@@ -1593,44 +1547,33 @@ class Scheduler:
                 # before the next span can be drafted
                 bstate = self._spec_cycle(bstate, active, results, st)
                 continue
-            # a super-step holds the host for N cycles' worth of device
-            # work, so anything needing per-cycle scheduling decisions
-            # (mid-prefill chunks, scheduled arrivals, preemption checks,
-            # admissions into free slots) keeps the per-cycle path — the
-            # same states the async drain below stays synchronous for
-            horizon = self._multi_horizon(active)
-            if (horizon > 1 and self._multi_ok(active)
-                    and not (prefilling or self._future or self._preempted
-                             or (self._queue
-                                 and (len(active) < self.num_slots
-                                      or self.preemption != "off")))):
-                with self._cycle_span(st, len(active), horizon=horizon,
-                                      multi=True):
-                    bstate, slots, out = self._issue_multi(
-                        bstate, active, st, self._host_tokens(active),
-                        horizon, self._stop_table(active))
-                bstate = self._drain_multi(bstate, out, slots, active,
-                                           results, st, horizon)
-                continue
-            # stay synchronous while prompts are mid-prefill (their next
-            # chunk must not wait behind a deferred readback), while
-            # scheduled arrivals or preempted requests are outstanding
-            # (the drain loop would defer their admission/preemption
-            # checks), or while a waiter could preempt a running slot
+            # stay synchronous, one step a cycle, while prompts are
+            # mid-prefill (their next chunk must not wait behind a deferred
+            # readback or a super-step), while scheduled arrivals or
+            # preempted requests are outstanding (the drain would defer
+            # their admission), or while a waiter could take a free slot or
+            # preempt a running one
             sync = bool(prefilling or self._future or self._preempted
-                        or (self._queue and (len(active) < self.num_slots
-                                             or self.preemption != "off")))
-            with self._cycle_span(st, len(active)):
-                bstate, slots, out = self._issue_cycle(
-                    bstate, active, st, self._host_tokens(active))
+                        or (self._queue and (len(active) < cfg.num_slots
+                                             or cfg.preemption != "off")))
+            horizon = 1 if sync else self._horizon(active)
+            slots = tuple(sorted(active))
+            args = {} if horizon == 1 else {"horizon": horizon, "multi": True}
+            with self._cycle_span(st, len(slots), **args):
+                bstate, out = self._issue(
+                    bstate, slots, self._host_tokens(active), st,
+                    horizon=horizon,
+                    stop_table=self._stop_table(active) if horizon > 1
+                    else None)
                 if sync:
                     bstate = self._retire_cycle(out, slots, active, results,
                                                 bstate, st, overlapped=False)
             if not sync:
-                bstate = self._drain_async(bstate, out, slots, active,
-                                           results, st)
-        st.cow_copies = pg.cow_copies - cow0
-        st.evictions = (radix.evictions - ev0) if radix is not None else 0
+                bstate = self._drain(bstate, out, slots, active, results, st,
+                                     horizon)
+        if pg is not None:
+            st.cow_copies = pg.cow_copies - cow0
+            st.evictions = (radix.evictions - ev0) if radix is not None else 0
         self._bstate = bstate
         return results
 
@@ -1641,8 +1584,8 @@ class Scheduler:
         while self._queue or self._future or active:
             self._drain_arrivals()
             self._wait_for_arrival(busy=bool(active))
-            while self._queue and len(active) < self.num_slots:
-                slot = next(i for i in range(self.num_slots)
+            while self._queue and len(active) < self.config.num_slots:
+                slot = next(i for i in range(self.config.num_slots)
                             if i not in active)
                 a = self._start(self._pop_next(), st)
                 if a.done:

@@ -204,8 +204,8 @@ def test_scheduler_config_equivalent_to_kwargs(setup):
     cfg = SchedulerConfig(num_slots=2, decode_horizon=4)
     s1 = Scheduler(session, config=cfg)
     s2 = Scheduler(session, num_slots=2, decode_horizon=4)
-    assert s1.num_slots == s2.num_slots == 2
-    assert s1.decode_horizon == s2.decode_horizon == 4
+    assert s1.config.num_slots == s2.config.num_slots == 2
+    assert s1.config.decode_horizon == s2.config.decode_horizon == 4
     assert s1.config == s2.config
 
 

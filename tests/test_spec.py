@@ -290,7 +290,7 @@ def test_rejected_draft_tokens_never_radix_cached(setup):
     st = sched.last_stats
     assert st.draft_tokens_proposed > st.draft_tokens_accepted  # rejections
     radix = sched._bstate["radix"]
-    bs = sched.block_size
+    bs = sched.config.block_size
     for p, rid in zip(ps, rids):
         realized = np.concatenate([p[0],
                                    res[rid].tokens[0]]).astype(np.int32)
